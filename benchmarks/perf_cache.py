@@ -1,9 +1,9 @@
 """Infrastructure perf: device-cache probe/commit + reuse-distance engine.
 
-Timings are CPU-host numbers (the container has no TPU); they measure the
-framework's host-side constants and the vectorized-engine speedup over the
-sequential reference, not TPU throughput (see EXPERIMENTS.md §Perf for the
-compiled-artifact roofline instead).
+Timings are for whatever backend runs the benchmark (the ``meta/run`` row
+names it).  Run on a CPU host they measure the framework's host-side
+constants and the vectorized-engine speedup over the sequential
+reference, not device throughput.
 
 Commit timings chain states (``state = commit(state, ...)``) so each call
 depends on the previous one's result -- measuring dependent update
@@ -15,8 +15,8 @@ The commit rows compare three engines over identical batches:
 * ``cache_commit_seq``     -- the fori_loop oracle (reference semantics)
 * ``cache_commit_vec``     -- the conflict-aware batch commit on the host
   engine, which is what the broker serves with on CPU backends
-* ``cache_commit_vec_xla`` -- the same algorithm as jnp ops; on this
-  container XLA CPU prices a B-index scatter at ~170ns/index, so this row
+* ``cache_commit_vec_xla`` -- the same algorithm as jnp ops; on a CPU
+  host XLA prices a B-index scatter at ~170ns/index, so this row
   mostly documents why the host engine exists (on accelerators the
   jnp/Pallas engines take over and the scatter objection disappears)
 

@@ -1,6 +1,7 @@
-"""Kernel micro-benchmarks: Pallas (interpret) vs jnp oracle correctness +
-host-side oracle timing (TPU wall-clock is out of scope on this container;
-the kernels' VMEM/roofline reasoning lives in the kernel docstrings)."""
+"""Kernel micro-benchmarks: Pallas kernel vs jnp oracle agreement plus
+oracle timing.  The kernels compile on an accelerator and run in the
+Pallas interpreter on CPU hosts (``repro.kernels.interpret``); each row's
+timing is for whatever backend ran it (the ``meta/run`` row names it)."""
 from __future__ import annotations
 
 import time
@@ -35,7 +36,7 @@ def run() -> List[str]:
         s0, t0s, c0 = ref(counts, phi)
     s0.block_until_ready()
     us = (time.time() - t0) / 10 * 1e6
-    s1, t1, c1 = topic_score_op(counts, phi, use_kernel=True, interpret=True)
+    s1, t1, c1 = topic_score_op(counts, phi, use_kernel=True)
     agree = float((t1 == t0s).mean())
     rows.append(
         csv_row(f"perf/topic_score/B={b}xV={v}xK={k}", us, f"kernel_top_agree={agree:.4f}")
@@ -51,7 +52,7 @@ def run() -> List[str]:
         out0 = ref_fn(table, bags)
     out0.block_until_ready()
     us = (time.time() - t0) / 20 * 1e6
-    out1 = embedding_bag_op(table, bags, use_kernel=True, interpret=True)
+    out1 = embedding_bag_op(table, bags, use_kernel=True)
     err = float(jnp.abs(out1 - out0).max())
     rows.append(csv_row("perf/embedding_bag/B=256xL=16xD=128", us, f"kernel_err={err:.1e}"))
 
@@ -66,7 +67,7 @@ def run() -> List[str]:
         o0 = ref_fn(q, kk, vv)
     o0.block_until_ready()
     us = (time.time() - t0) / 20 * 1e6
-    o1 = decode_attention_op(q, kk, vv, 2000, scale=128**-0.5, use_kernel=True, interpret=True)
+    o1 = decode_attention_op(q, kk, vv, 2000, scale=128**-0.5, use_kernel=True)
     err = float(jnp.abs(o1 - o0).max())
     rows.append(csv_row("perf/decode_attention/B4xS2048", us, f"kernel_err={err:.1e}"))
     return rows

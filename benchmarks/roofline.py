@@ -110,7 +110,6 @@ def autotune(
     if quick:
         buckets, bms, trials = (256,), (64, 256), 2
     backend = jax.default_backend()
-    interpret = backend == "cpu"
     s, w, v = 4096, 4, 8
     rng = np.random.default_rng(0)
     ks = jnp.asarray(rng.integers(0, 2**32, size=(s, 4 * w), dtype=np.uint32))
@@ -135,7 +134,7 @@ def autotune(
             )
             step = jax.jit(
                 lambda ks, value, bm=bm, args=args: serve_fused_op(
-                    ks, value, use_kernel=True, interpret=interpret, bm=bm, **args
+                    ks, value, use_kernel=True, bm=bm, **args
                 )
             )
             jax.tree_util.tree_map(  # compile outside the timed region

@@ -19,13 +19,15 @@ import time
 
 
 def _git_rev() -> str:
+    """The checkout's short commit, or "unknown" where the copy is not a
+    git repository or has no git binary."""
     try:
         return subprocess.check_output(
             ["git", "rev-parse", "--short", "HEAD"],
             cwd=os.path.dirname(os.path.abspath(__file__)),
             stderr=subprocess.DEVNULL,
         ).decode().strip()
-    except Exception:  # noqa: BLE001
+    except (OSError, subprocess.CalledProcessError):
         return "unknown"
 
 
@@ -84,6 +86,10 @@ def main() -> None:
         help="machine-readable mirror of the CSV rows ('' disables)",
     )
     args = ap.parse_args()
+
+    from repro import configure_compile_cache
+
+    configure_compile_cache()
 
     from . import (
         fig6_miss_distance,

@@ -46,12 +46,13 @@ an eviction -- shape-bucketed serving pads ragged batches with them.
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple, Union
+from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..interpret import resolve_interpret
 from .kernel import PAD_HI, PAD_LO, conflict_round, is_pad
 from .kernel import probe_and_commit as _kernel_call
 from .ref import probe_and_commit_ref  # noqa: F401  (re-exported for tests)
@@ -238,7 +239,7 @@ def probe_and_commit_op(
     epochs: jnp.ndarray = None,  # (B,) uint32 write epochs (None -> 0)
     min_epoch: jnp.ndarray = None,  # (B,) uint32 freshness floor (None -> 0)
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     bm: int = 256,
 ) -> Dict[str, jnp.ndarray]:
     """Fused probe + batch commit over the packed state array.
@@ -307,7 +308,7 @@ def probe_and_commit_op(
             col(s_minep),
             jnp.reshape(clock.astype(jnp.int32), (1, 1)),
             bm=bm,
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )
         r_rows = r_rows[:b]
         p_hit = p_hit[:b, 0] != 0
@@ -398,7 +399,7 @@ def serve_fused_op(
     epochs: jnp.ndarray = None,  # (B,) uint32 write epochs (None -> 0)
     min_epoch: jnp.ndarray = None,  # (B,) uint32 freshness floor (None -> 0)
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     bm: int = 256,
 ) -> Dict[str, jnp.ndarray]:
     """One-dispatch serve: deferred-fill apply + fused probe/commit +
@@ -412,7 +413,7 @@ def serve_fused_op(
     inert) and lands *before* the probe reads any value row.
 
     ``use_kernel=True`` routes the whole step through the fused Pallas
-    serve kernel (one device dispatch; interpret=True on CPU hosts);
+    serve kernel (one device dispatch; the interpreter on CPU hosts);
     otherwise the same phases run as jnp ops reusing
     :func:`probe_and_commit_op`, so the two paths -- and the sequential
     numpy oracle :func:`serve_fused_ref` -- are bit-exact by shared
@@ -492,7 +493,7 @@ def serve_fused_op(
             value.reshape(nslots, v),
             jnp.reshape(clock.astype(jnp.int32), (1, 1)),
             bm=bm,
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )
     )
     r_rows = r_rows[:b]

@@ -5,6 +5,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from ..interpret import resolve_interpret
 from .kernel import decode_attention
 from .ref import decode_attention_ref
 
@@ -18,7 +19,7 @@ def decode_attention_op(
     softcap: Optional[float] = None,
     window: Optional[int] = None,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     if not use_kernel:
         return decode_attention_ref(q, k, v, cur_len, scale, softcap, window)
@@ -33,5 +34,5 @@ def decode_attention_op(
         v = jnp.pad(v, cfg)
     return decode_attention(
         q, k, v, cur_len, scale=scale, softcap=softcap, window=window, bs=bs,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )

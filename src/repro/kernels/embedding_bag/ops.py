@@ -6,9 +6,12 @@ scalar-prefetch kernel or the jnp oracle.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
+from ..interpret import resolve_interpret
 from .kernel import embedding_bag
 from .ref import embedding_bag_ref
 
@@ -18,7 +21,7 @@ def embedding_bag_op(
     bags: jnp.ndarray,  # (B, L) int32, padded with -1
     mode: str = "sum",
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     b, l = bags.shape
     flat = bags.reshape(-1)
@@ -30,7 +33,10 @@ def embedding_bag_op(
     table_ext = jnp.concatenate([table, jnp.zeros((1, d), table.dtype)], axis=0)
     idx = jnp.where(valid, flat, v)
     if use_kernel:
-        out = embedding_bag(table_ext, idx, segments, n_bags=b, interpret=interpret)
+        out = embedding_bag(
+            table_ext, idx, segments, n_bags=b,
+            interpret=resolve_interpret(interpret),
+        )
     else:
         out = embedding_bag_ref(table_ext, idx, segments, n_bags=b)
     if mode == "mean":

@@ -1,15 +1,18 @@
 """Public op: topic scoring with kernel/oracle dispatch.
 
 ``topic_score_op`` pads inputs to MXU-aligned shapes, invokes the Pallas
-kernel (interpret=True on CPU hosts), and un-pads.  ``use_kernel=False``
+kernel (the interpreter on CPU hosts), and un-pads.  ``use_kernel=False``
 routes to the pure-jnp oracle -- the serving pipeline flips this on TPU.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..interpret import resolve_interpret
 from .kernel import topic_score
 from .ref import topic_score_ref
 
@@ -28,7 +31,7 @@ def topic_score_op(
     counts: jnp.ndarray,
     log_phi_t: jnp.ndarray,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """counts (B, V) f32, log_phi_t (V, K) f32 ->
     (scores (B, K), top (B,) int32, conf (B,) f32)."""
@@ -44,7 +47,9 @@ def topic_score_op(
     if phi_p.shape[1] != k:
         neg = jnp.full((phi_p.shape[0], phi_p.shape[1] - k), -1e9, jnp.float32)
         phi_p = jnp.concatenate([phi_p[:, :k], neg], axis=1)
-    scores, top, conf = topic_score(counts_p, phi_p, interpret=interpret)
+    scores, top, conf = topic_score(
+        counts_p, phi_p, interpret=resolve_interpret(interpret)
+    )
     # all-zero count rows are degenerate (uniform scores): clamp into range
     top = jnp.minimum(top, k - 1)
     return scores[:b, :k], top[:b], conf[:b]

@@ -20,18 +20,11 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax defaults to Auto
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, n_pods: int = 2) -> Mesh:

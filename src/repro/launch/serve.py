@@ -18,15 +18,16 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import math
 import sys
 import tempfile
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+from .. import configure_compile_cache
 from ..configs.registry import get_arch
 from ..core import CacheSpec
 from ..core.spec import STRATEGIES
@@ -92,7 +93,7 @@ def _parse_fault_profile(s: str):
         )
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b")
     ap.add_argument("--requests", type=int, default=50_000)
@@ -239,22 +240,12 @@ def main(argv=None) -> int:
         "popularity phases (oracle topics, no LDA) instead of the "
         "calibrated stationary log",
     )
-    args = ap.parse_args(argv)
+    return ap
 
-    faults = list(args.fault_shard) + list(args.fault_profile)
-    if faults and not args.open_loop:
-        ap.error("--fault-shard/--fault-profile need --open-loop (fault "
-                 "schedules run on the open-loop virtual clock)")
-    for shard, _ in faults:
-        if not 0 <= shard < args.shards:
-            ap.error(f"--fault shard index {shard} out of range for "
-                     f"--shards {args.shards}")
 
-    # build the declarative spec up front so configuration errors (e.g. an
-    # SDC-section strategy without --f-ts, or a bad shard/routing combo)
-    # fail before the expensive log generation; the same spec drives the
-    # exact and reuse-distance engines bit-identically
-    spec = ServingSpec(
+def spec_from_args(args, faults=()) -> ServingSpec:
+    """The declarative serving spec the CLI's arguments describe."""
+    return ServingSpec(
         cache=CacheSpec.from_strategy(
             args.strategy, args.entries, f_s=args.f_s, f_t=args.f_t, f_ts=args.f_ts
         ),
@@ -300,16 +291,31 @@ def main(argv=None) -> int:
             else None
         ),
     )
-    print(f"serving spec: {spec.to_json()}")
 
-    if args.drift_phases > 0:
-        print(f"generating drift stream ({args.drift_phases} popularity phases) ...")
+
+@dataclasses.dataclass
+class Stream:
+    """A generated query stream with its trained topic assignment."""
+
+    synth: object  # the generator's output (keys, timestamps, topics)
+    log: VecLog  # train/test split over the keys
+    stats: VecStats  # training statistics the cache is compiled from
+    key_topic: np.ndarray  # query id -> topic (-1 = no topic)
+
+    def topic_of(self, q: np.ndarray) -> np.ndarray:
+        return self.key_topic[q]
+
+
+def build_stream(requests: int, drift_phases: int = 0) -> Stream:
+    """The seeded query stream: the calibrated log with LDA topics, or a
+    piecewise-stationary drift stream with ``drift_phases`` phases."""
+    if drift_phases > 0:
         dcfg = DriftConfig(
-            n_requests=args.requests,
+            n_requests=requests,
             n_topics=16,
-            queries_per_topic=max(args.requests // 64, 64),
-            n_notopic_queries=max(args.requests // 40, 64),
-            n_phases=args.drift_phases,
+            queries_per_topic=max(requests // 64, 64),
+            n_notopic_queries=max(requests // 40, 64),
+            n_phases=drift_phases,
             seed=11,
         )
         synth = generate_drifting(dcfg)
@@ -318,44 +324,100 @@ def main(argv=None) -> int:
         # test is the allocation's staleness, not topic discovery
         log = VecLog(
             keys=synth.keys,
-            n_train=args.requests // max(args.drift_phases, 1),
+            n_train=requests // drift_phases,
             key_topic=synth.true_topic,
         )
-        stats = VecStats.from_log(log)
-        key_topic = synth.true_topic
-    else:
-        print("generating calibrated query log + LDA topics ...")
-        cfg = SynthConfig(
-            n_requests=args.requests,
-            n_topics=16,
-            n_topical_queries=args.requests // 10,
-            n_notopic_queries=args.requests // 20,
-            vocab_size=512,
-            seed=11,
-        )
-        synth = generate(cfg)
-        pipe = run_pipeline(synth, train_frac=0.5, lda_iters=15, lda_subsample=5_000)
-        log, stats = pipe.log, pipe.stats
-        key_topic = pipe.assignment.key_topic
+        return Stream(synth, log, VecStats.from_log(log), synth.true_topic)
+    cfg = SynthConfig(
+        n_requests=requests,
+        n_topics=16,
+        n_topical_queries=requests // 10,
+        n_notopic_queries=requests // 20,
+        vocab_size=512,
+        seed=11,
+    )
+    synth = generate(cfg)
+    pipe = run_pipeline(synth, train_frac=0.5, lda_iters=15, lda_subsample=5_000)
+    return Stream(synth, pipe.log, pipe.stats, pipe.assignment.key_topic)
 
-    arch = get_arch(args.arch)
-    mcfg = arch.smoke_config
+
+def model_scores(params, tokens, mcfg, value_dim: int):
+    """The backend's answer for a batch of query token windows: the top
+    ``value_dim`` token ids of the LM's last-position logits."""
+    logits, _ = tf.forward(params, tokens, mcfg)
+    return jax.lax.top_k(logits[:, -1], value_dim)[1]
+
+
+def build_backend(arch: str, value_dim: int, chunk: int):
+    """The miss backend: a reduced-config LM scoring each query, answering
+    ``value_dim`` doc ids per query.
+
+    Every call runs in ``chunk``-row pieces, the last one padded, so the
+    model compiles one shape and its activations stay bounded however
+    many rows arrive at once -- the static-layer preload hands it the
+    whole static key set in one call."""
+    mcfg = get_arch(arch).smoke_config
     params = tf.init_params(jax.random.PRNGKey(0), mcfg)
-
-    @jax.jit
-    def model_scores(tokens):
-        logits, _ = tf.forward(params, tokens, mcfg)
-        return jax.lax.top_k(logits[:, -1], args.value_dim)[1]
+    scores = jax.jit(
+        functools.partial(model_scores, mcfg=mcfg, value_dim=value_dim)
+    )
 
     def backend(qids: np.ndarray) -> np.ndarray:
-        # query text stub: derive a token window from the query id
-        tokens = (qids[:, None] * 31 + np.arange(8)[None, :]) % mcfg.vocab_size
-        return np.asarray(model_scores(jnp.asarray(tokens, jnp.int32)), np.int32)
+        qids = np.asarray(qids, np.int64)
+        out = np.empty((len(qids), value_dim), np.int32)
+        for lo in range(0, len(qids), chunk):
+            part = qids[lo : lo + chunk]
+            # query text stub: derive a token window from the query id
+            tokens = np.zeros((chunk, 8), np.int32)
+            tokens[: len(part)] = (
+                part[:, None] * 31 + np.arange(8)[None, :]
+            ) % mcfg.vocab_size
+            out[lo : lo + len(part)] = np.asarray(scores(params, tokens))[: len(part)]
+        return out
+
+    return backend
+
+
+def build_cluster(spec: ServingSpec, stream: Stream, backend) -> Cluster:
+    """Compile ``spec`` into a cluster over ``stream``'s training stats,
+    with ``backend`` answering misses and preloading the static layer."""
+    return Cluster.from_spec(
+        spec, stream.stats, [backend], topic_of=stream.topic_of,
+        value_fn=backend,
+    )
+
+
+def main(argv=None) -> int:
+    ap = build_parser()
+    args = ap.parse_args(argv)
+
+    faults = list(args.fault_shard) + list(args.fault_profile)
+    if faults and not args.open_loop:
+        ap.error("--fault-shard/--fault-profile need --open-loop (fault "
+                 "schedules run on the open-loop virtual clock)")
+    for shard, _ in faults:
+        if not 0 <= shard < args.shards:
+            ap.error(f"--fault shard index {shard} out of range for "
+                     f"--shards {args.shards}")
+
+    # build the declarative spec up front so configuration errors (e.g. an
+    # SDC-section strategy without --f-ts, or a bad shard/routing combo)
+    # fail before the expensive log generation; the same spec drives the
+    # exact and reuse-distance engines bit-identically
+    spec = spec_from_args(args, faults)
+    print(f"serving spec: {spec.to_json()}")
+    configure_compile_cache()
+
+    if args.drift_phases > 0:
+        print(f"generating drift stream ({args.drift_phases} popularity phases) ...")
+    else:
+        print("generating calibrated query log + LDA topics ...")
+    stream = build_stream(args.requests, args.drift_phases)
+    synth, log = stream.synth, stream.log
+    backend = build_backend(args.arch, args.value_dim, chunk=args.batch)
 
     test = log.test_keys
-    with Cluster.from_spec(
-        spec, stats, [backend], topic_of=lambda q: key_topic[q], value_fn=backend
-    ) as cluster:
+    with build_cluster(spec, stream, backend) as cluster:
         if args.open_loop:
             policy = spec.compiled_batch_policy()
             if args.deadline_ms > 0:

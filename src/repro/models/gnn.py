@@ -144,7 +144,7 @@ def forward_dist(
     layer -- versus the baseline's all-reduce over the 12x-wider (N, d_agg)
     aggregate tensor that GSPMD emits for position-sharded edges.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = x.shape[0]
@@ -183,7 +183,7 @@ def forward_dist(
         mesh=mesh,
         in_specs=(P(spec, None), P(None, spec)),
         out_specs=P(spec, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, edge_index)
 

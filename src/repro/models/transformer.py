@@ -374,7 +374,7 @@ def _moe_ffn(moe_p: Params, x: jnp.ndarray, cfg: TransformerConfig) -> Tuple[jnp
         # single-shard path: wi reshaped (E, D, 2, F) -> dense local compute
         return _moe_local(x, moe_p["router"], moe_p["wi"], moe_p["wo"], cfg, None)
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = get_moe_mesh()
@@ -407,7 +407,7 @@ def _moe_ffn(moe_p: Params, x: jnp.ndarray, cfg: TransformerConfig) -> Tuple[jnp
             P(fsdp_spec, tp, None),
         ),
         out_specs=(P(batch, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     # pad tokens to the shard count (decode at tiny batch): padded zero
     # tokens route like any token and are sliced away after

@@ -18,9 +18,11 @@ choice per bind keeps the trace count at O(#buckets)).  No table, an
 unreadable table, or a missing entry all fall back to :data:`DEFAULT_BM`
 -- the autotuner is an optimization, never a dependency.
 
-The table location is ``REPRO_AUTOTUNE_PATH`` when set, else
-``BENCH_autotune.json`` in the working directory (where the benchmark
-writes it and CI uploads it as an artifact).
+The broker reads a table only from an explicit ``REPRO_AUTOTUNE_PATH``:
+a stray file in the working directory never changes what a deployment
+compiles.  The autotuner writes to that path when set, else to
+``BENCH_autotune.json`` in the working directory (which CI uploads as an
+artifact).
 """
 from __future__ import annotations
 
@@ -41,14 +43,17 @@ AUTOTUNE_SCHEMA = 1
 _cache: Dict[str, Optional[dict]] = {}
 
 
-def table_path() -> str:
-    """The autotune table's location (env override, else cwd default)."""
-    return os.environ.get(ENV_PATH, DEFAULT_PATH)
+def table_path() -> Optional[str]:
+    """The autotune table the broker reads (None: no table configured)."""
+    return os.environ.get(ENV_PATH) or None
 
 
 def load_table(path: Optional[str] = None) -> Optional[dict]:
-    """Load (and memoize) the autotune table; None when absent/corrupt."""
+    """Load (and memoize) the autotune table; None when unconfigured,
+    absent or corrupt."""
     path = path or table_path()
+    if path is None:
+        return None
     if path in _cache:
         return _cache[path]
     table = None
@@ -70,7 +75,7 @@ def clear_cache() -> None:
 
 def save_table(table: dict, path: Optional[str] = None) -> str:
     """Persist an autotune table (and invalidate the memo)."""
-    path = path or table_path()
+    path = path or table_path() or DEFAULT_PATH
     table = dict(table, schema=AUTOTUNE_SCHEMA)
     with open(path, "w") as f:
         json.dump(table, f, indent=1, sort_keys=True)

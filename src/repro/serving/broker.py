@@ -158,6 +158,7 @@ class Broker:
         freshness: Optional[FreshnessSpec] = None,
         fused_one_call: bool = True,
         aot_warmup: bool = False,
+        device: Optional[jax.Device] = None,
     ):
         self.cache = cache
         #: declarative configuration this cache was compiled from (embedded
@@ -170,7 +171,6 @@ class Broker:
                 "callable was provided; the broker would silently admit "
                 "everything the spec says to filter"
             )
-        self.state = dict(cache.init_state)
         self.backends = list(backends)
         self.topic_of = topic_of
         self.admission = admission
@@ -182,7 +182,7 @@ class Broker:
         self.coalesce = coalesce
         #: serve through the fused probe-and-commit path (one device call
         #: for a fully-hit batch); ``use_kernel`` routes the conflict
-        #: resolution through the Pallas kernel (interpret on CPU hosts)
+        #: resolution through the Pallas kernel (the interpreter on CPU hosts)
         self.fused = fused
         #: whether warmup() runs at every cache (re)bind -- construction
         #: and rebalance -- so no live request waits on a jax trace
@@ -195,6 +195,12 @@ class Broker:
             raise ValueError(f"engine must be auto|host|device, got {engine!r}")
         self.engine = engine
         self.use_kernel = use_kernel
+        #: the device the cache state lives on (None: JAX's default).  A
+        #: sharded cluster pins each shard to its own chip; every state the
+        #: broker adopts -- built, restored, re-initialized or migrated --
+        #: lands there before a jitted entry compiles against it
+        self.device = device if engine == "device" else None
+        self.state = self._placed(dict(cache.init_state))
         #: static-shape contract: pad batches up to shape buckets with the
         #: reserved pad key.  Auto (bucket=None): the jit-compiled device
         #: engine buckets (pow2), the host engine serves unpadded (numpy
@@ -262,6 +268,12 @@ class Broker:
         self._pool = ThreadPoolExecutor(max_workers=max(2, len(backends)))
         self._closed = False
 
+    def _placed(self, state):
+        """``state`` on this broker's device (as given when unpinned)."""
+        if self.device is None:
+            return state
+        return jax.device_put(state, self.device)
+
     def _traced(self, name: str, fn):
         """Wrap ``fn`` so each jax trace bumps ``trace_counts[name]`` --
         the wrapper body only executes while tracing, so the counter is
@@ -296,8 +308,6 @@ class Broker:
         bucket shape (:meth:`warmup`), so neither a fresh broker nor a
         just-rebalanced one ever makes a live request wait on a trace."""
         self.cache = cache
-        # compile the kernel on real accelerators; emulate on CPU
-        interpret = jax.default_backend() == "cpu"
         # kernel request-tile size: the autotuner's persisted winner for
         # this backend at the top serving bucket (DEFAULT_BM without a
         # table); one static choice per bind keeps traces at O(#buckets)
@@ -327,7 +337,6 @@ class Broker:
                     functools.partial(
                         cache.probe_and_commit,
                         use_kernel=self.use_kernel,
-                        interpret=interpret,
                         bm=self._bm,
                     ),
                 )
@@ -341,7 +350,6 @@ class Broker:
                     functools.partial(
                         cache.fill_probe_and_commit,
                         use_kernel=self.use_kernel,
-                        interpret=interpret,
                         bm=self._bm,
                     ),
                 )
@@ -355,7 +363,6 @@ class Broker:
                     functools.partial(
                         cache.serve_one_call,
                         use_kernel=self.use_kernel,
-                        interpret=interpret,
                         bm=self._bm,
                     ),
                 )
@@ -460,6 +467,7 @@ class Broker:
         admitted: Optional[np.ndarray] = None,
         admission: Optional[Callable[[np.ndarray], np.ndarray]] = None,
         cache: Optional[STDDeviceCache] = None,
+        device: Optional[jax.Device] = None,
     ) -> "Broker":
         """Compile a :class:`repro.serving.spec.ServingSpec` to one broker.
 
@@ -472,7 +480,7 @@ class Broker:
         sharded deployments go through
         :meth:`repro.serving.cluster.Cluster.from_spec`, which hands each
         shard its slice of the cache via ``cache=`` so the rest of the
-        spec compiles in exactly one place.
+        spec compiles in exactly one place, and its ``device``.
         """
         if cache is None:
             cache = STDDeviceCache.from_spec(
@@ -501,6 +509,7 @@ class Broker:
             freshness=spec.freshness,
             fused_one_call=spec.fused_one_call,
             aot_warmup=spec.aot_warmup,
+            device=device,
         )
 
     # -- lifecycle -----------------------------------------------------------
@@ -1075,7 +1084,7 @@ class Broker:
             engine="host" if self.engine == "host" else "vec",
             bucket=self.bucket,
         )
-        self.state = new_state
+        self.state = self._placed(new_state)
         self._bind_cache(new_cache)
         self.stats.rebalances += 1
         key_hi, _, _ = unpack_state({"ks": np.asarray(new_state["ks"])})
@@ -1179,7 +1188,7 @@ class Broker:
             self._bind_cache(pending_cache)
         if "freshness" in tree:
             self.freshness.load(tree["freshness"])
-        self.state = jax.tree.map(jnp.asarray, tree["cache"])
+        self.state = jax.device_put(tree["cache"], self.device)
         for k, v in tree["stats"].items():
             if k == "topic_counts":
                 # present only when a tracker exists (tree_like mirrors the

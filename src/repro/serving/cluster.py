@@ -72,24 +72,19 @@ from .spec import DispatchSpec, ServingSpec
 MANIFEST_NAME = "cluster.json"
 
 
-def _place_brokers(brokers: Sequence[Broker]) -> None:
-    """Pin each device-engine shard broker's state to its own device
-    (round-robin via launch.mesh) when the backend has more than one --
-    shard serves then overlap on hardware, not just in dispatch order.
-    No-op on single-device hosts and for host-engine brokers."""
-    if not any(b.engine == "device" for b in brokers):
-        return
+def _shard_devices(n_shards: int) -> list:
+    """The device each shard broker pins its state to: its own (round-robin
+    via launch.mesh) when the backend has more than one, so shard serves
+    overlap on hardware and not just in dispatch order; None (JAX's default
+    device) on single-device hosts.  Host-engine brokers ignore it."""
     import jax
 
     from ..launch.mesh import shard_devices  # deferred: launch imports serving
 
     devices = jax.devices()
-    if len(devices) <= 1:
-        return
-    for b, dev in zip(brokers, shard_devices(len(brokers), devices)):
-        if b.engine == "device":
-            b.state = jax.device_put(b.state, dev)
-            b.device = dev
+    if n_shards <= 1 or len(devices) <= 1:
+        return [None] * n_shards
+    return shard_devices(n_shards, devices)
 
 
 def _shard_dir(ckpt_dir: str, i: int) -> str:
@@ -250,6 +245,9 @@ class Cluster:
         static_keys = spec.cache.device_static_keys(stats)
         static_shard = spec.shard_of(static_keys, topics=key_topic[static_keys])
         configs = spec.device_configs(stats.topic_distinct)
+        # placement precedes construction: a broker built with aot_warmup
+        # compiles against its state, which must already be on its device
+        devices = _shard_devices(len(configs))
         brokers = []
         for i, cfg in enumerate(configs):
             keys_i = static_keys[static_shard == i]
@@ -262,7 +260,7 @@ class Cluster:
             )
             broker = Broker.from_spec(
                 spec, stats, backends, topic_of=topic_of, admission=gate,
-                cache=cache,
+                cache=cache, device=devices[i],
             )
             if spec.shards > 1:
                 # distinct per-shard identity in the embedded spec, so
@@ -273,7 +271,6 @@ class Cluster:
                     name=f"{spec.cache.name or 'cache'}:shard{i}of{spec.shards}",
                 )
             brokers.append(broker)
-        _place_brokers(brokers)
         cluster = cls(spec, brokers, topic_of, parallel=parallel)
         # everything needed to rebuild the shard set at a different
         # count: elastic resharding re-runs this compilation, then
@@ -757,7 +754,7 @@ class Cluster:
             inj.restart()
         # replacement process: in-memory cache state and stats are gone
         broker._pending_fill = None
-        broker.state = dict(broker.cache.init_state)
+        broker.state = broker._placed(dict(broker.cache.init_state))
         for f in dataclasses.fields(BrokerStats):
             if f.name != "topic_counts":
                 setattr(broker.stats, f.name, 0)

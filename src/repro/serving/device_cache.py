@@ -540,7 +540,7 @@ class STDDeviceCache:
 
     def commit_vectorized(
         self, state, h_hi, h_lo, part, values, admit, epochs=None, min_epoch=None,
-        use_kernel: bool = False, interpret: bool = True, bm: int = 256,
+        use_kernel: bool = False, interpret: Optional[bool] = None, bm: int = 256,
     ):
         """Conflict-aware batch commit, bit-exact with :meth:`commit`.
 
@@ -568,7 +568,7 @@ class STDDeviceCache:
 
     def probe_and_commit(
         self, state, h_hi, h_lo, part, admit, epochs=None, min_epoch=None,
-        use_kernel: bool = False, interpret: bool = True, bm: int = 256,
+        use_kernel: bool = False, interpret: Optional[bool] = None, bm: int = 256,
     ):
         """Fused serve step: probe + key/stamp commit in one device call.
 
@@ -610,7 +610,7 @@ class STDDeviceCache:
     def fill_probe_and_commit(
         self, state, f_set_idx, f_wrote, f_way, f_values, h_hi, h_lo, part, admit,
         epochs=None, min_epoch=None,
-        use_kernel: bool = False, interpret: bool = True, bm: int = 256,
+        use_kernel: bool = False, interpret: Optional[bool] = None, bm: int = 256,
     ):
         """Double-buffered serve step: apply the *previous* batch's
         deferred value fill, then probe-and-commit the current batch, in
@@ -633,7 +633,7 @@ class STDDeviceCache:
     def serve_one_call(
         self, state, f_set_idx, f_wrote, f_way, f_values, h_hi, h_lo, part, admit,
         epochs=None, min_epoch=None,
-        use_kernel: bool = False, interpret: bool = True, bm: int = 256,
+        use_kernel: bool = False, interpret: Optional[bool] = None, bm: int = 256,
     ):
         """One-dispatch serve step: the previous batch's deferred value
         fill, the atomic probe (with freshness), the conflict-aware

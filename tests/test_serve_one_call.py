@@ -18,6 +18,8 @@ Pins the three contracts PR 10 introduced:
   duplicate keys (hypothesis sweeps the same space harder when
   installed).
 """
+import json
+
 import jax
 import numpy as np
 import pytest
@@ -418,6 +420,24 @@ def test_autotune_corrupt_table_falls_back(tmp_path, monkeypatch):
         f.write('{"schema": 99, "entries": {}}')  # wrong schema version
     assert autotune.load_table() is None
     autotune.clear_cache()
+
+
+def test_autotune_ignores_a_stray_table_in_the_working_directory(
+    tmp_path, monkeypatch
+):
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.delenv(autotune.ENV_PATH, raising=False)
+    autotune.clear_cache()
+    backend = jax.default_backend()
+    (tmp_path / autotune.DEFAULT_PATH).write_text(json.dumps({
+        "schema": autotune.AUTOTUNE_SCHEMA,
+        "entries": {f"{backend}/256": {"bm": 64}},
+    }))
+    try:
+        assert autotune.table_path() is None
+        assert autotune.best_bm(backend, 256) == autotune.DEFAULT_BM
+    finally:
+        autotune.clear_cache()
 
 
 def test_broker_picks_up_autotuned_bm(tmp_path, monkeypatch):

@@ -49,8 +49,8 @@ def main():
     w("All artifacts regenerable: `dryrun_results.json` from "
       "`python -m repro.launch.dryrun --all --both-meshes --json ...`, the "
       "table numbers from `python -m benchmarks.run`, the §Perf numbers from "
-      "`python tools/hillclimb.py`.  Hardware model: " + HW + " (the container "
-      "is CPU-only: compile-time artifacts, not wall clocks).\n")
+      "`python tools/hillclimb.py`.  Hardware model: " + HW + " (numbers are "
+      "compile-time artifacts from a CPU host, not wall clocks).\n")
 
     # ---------------- paper claims ----------------
     w("## §Paper-claims — validation against the paper's own results\n")

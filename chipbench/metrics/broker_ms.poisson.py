@@ -1,0 +1,6 @@
+"""Host milliseconds in Cluster.serve outside the backend per served batch, open loop."""
+from chipbench import readers
+
+
+def read(run):
+    return readers.broker_ms(run, "open")

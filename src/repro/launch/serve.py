@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import functools
 import math
 import sys
 import tempfile
@@ -358,9 +357,10 @@ def build_backend(arch: str, value_dim: int, chunk: int):
     whole static key set in one call."""
     mcfg = get_arch(arch).smoke_config
     params = tf.init_params(jax.random.PRNGKey(0), mcfg)
-    scores = jax.jit(
-        functools.partial(model_scores, mcfg=mcfg, value_dim=value_dim)
-    )
+
+    @jax.jit
+    def backend_scores(params, tokens):  # compiles as jit_backend_scores
+        return model_scores(params, tokens, mcfg=mcfg, value_dim=value_dim)
 
     def backend(qids: np.ndarray) -> np.ndarray:
         qids = np.asarray(qids, np.int64)
@@ -372,7 +372,8 @@ def build_backend(arch: str, value_dim: int, chunk: int):
             tokens[: len(part)] = (
                 part[:, None] * 31 + np.arange(8)[None, :]
             ) % mcfg.vocab_size
-            out[lo : lo + len(part)] = np.asarray(scores(params, tokens))[: len(part)]
+            scores = np.asarray(backend_scores(params, tokens))
+            out[lo : lo + len(part)] = scores[: len(part)]
         return out
 
     return backend

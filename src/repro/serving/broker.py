@@ -34,7 +34,22 @@ the same bucket padding on its data-dependent miss/refresh sub-batches.
 
 Every jitted entry point counts its traces in ``Broker.trace_counts``
 (the python wrapper body only runs when jax traces), which is what the
-compile-count regression tests pin.
+compile-count regression tests pin, and compiles as a program named
+``jit_wrapper.<entry>`` (``jit_wrapper.one_call``, ``jit_wrapper.fill``,
+...), so a device trace tells the entries apart.
+
+Tracing: ``serve`` opens ``jax.profiler.TraceAnnotation`` spans for its
+phases -- ``broker.serve`` (metadata: shard, batch seq, b, padded bp)
+around ``broker.prepare`` (hash, route, pad, freshness arrays,
+admission), ``broker.dispatch`` (the pending fill plan, the uploads and
+the jitted call, which returns once enqueued), ``broker.readback`` (the
+wait for the step and the copy of its outputs), ``broker.miss``
+(coalescing and the backend) and ``broker.fill_plan`` (the deferred
+fill's plan), plus ``broker.flush`` for a standalone fill.  They land in
+the same trace, on the same clock, as the device's operations, and cost
+one check each when no profiler session is active.  ``BrokerStats``
+counts the transfers behind them (``readbacks``, ``uploads``) and the
+conflict replay's depth (``replay_rounds``).
 
 Fault tolerance: `checkpoint` / `restore` snapshot the full cache state
 atomically (repro.train.checkpoint); a broker can restart mid-stream and
@@ -52,6 +67,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.alloc import allocation_divergence
 from ..core.spec import CacheSpec
@@ -115,6 +131,19 @@ class BrokerStats:
     #: invalidation events applied (slots zeroed for key events, one per
     #: topic/flush event for epoch-bump invalidations)
     invalidations: int = 0
+    #: device->host array fetches ``serve`` issued (seven per batch on the
+    #: one-call path: hit, layer, stale, value, then wrote, set_idx, way)
+    readbacks: int = 0
+    #: host->device arrays the serving path uploaded (ten per batch on the
+    #: one-call path: four fill-plan arrays and six request arrays);
+    #: warm-up uploads are not counted
+    uploads: int = 0
+    #: conflict-replay rounds, summed over batches: the largest number of
+    #: requests of the padded batch that share one set (``max(seg_len)``
+    #: of ``ops.plan_segments``), counted from the set indices the fill
+    #: plan fetches -- on batches that wrote, on the host engine and the
+    #: deferred-fill device path
+    replay_rounds: int = 0
     #: the online popularity tracker's state: exponentially-decayed served
     #: request counts per tracked topic (sorted id order) + a trailing
     #: no-topic bucket; shares memory with ``Broker.tracker`` and is None
@@ -125,6 +154,9 @@ class BrokerStats:
     def hit_rate(self) -> float:
         return self.hits / self.requests if self.requests else 0.0
 
+
+#: counters newer than some checkpoints: a restore reads them as 0
+_LATER_COUNTERS = ("readbacks", "uploads", "replay_rounds")
 
 Backend = Callable[[np.ndarray], np.ndarray]  # query ids -> values (B, V)
 
@@ -264,6 +296,8 @@ class Broker:
             if freshness is not None
             else None
         )
+        #: this broker's index in its cluster (trace metadata only)
+        self.shard = 0
         self._bind_cache(cache)
         self._pool = ThreadPoolExecutor(max_workers=max(2, len(backends)))
         self._closed = False
@@ -278,13 +312,16 @@ class Broker:
         """Wrap ``fn`` so each jax trace bumps ``trace_counts[name]`` --
         the wrapper body only executes while tracing, so the counter is
         exactly the number of compiled shapes (cumulative across
-        rebalances, which re-bind fresh jits)."""
+        rebalances, which re-bind fresh jits).  The wrapper is named
+        ``wrapper.<name>``, so its program compiles as
+        ``jit_wrapper.<name>``."""
         counts = self.trace_counts
 
         def wrapper(*args, **kwargs):
             counts[name] = counts.get(name, 0) + 1
             return fn(*args, **kwargs)
 
+        wrapper.__name__ = wrapper.__qualname__ = f"wrapper.{name}"
         return wrapper
 
     def _counted(self, name: str, fn):
@@ -292,14 +329,30 @@ class Broker:
         ``dispatch_counts[name]`` -- unlike ``_traced`` this wrapper sits
         outside the jit boundary and runs on every dispatch, traced or
         cache-hit, so the counter is exactly the number of device calls
-        issued through the entry point."""
+        issued through the entry point.  ``wrapper.__wrapped__`` is the
+        jitted entry itself (``.lower(...)`` shows its compiled program)."""
         counts = self.dispatch_counts
 
         def wrapper(*args, **kwargs):
             counts[name] = counts.get(name, 0) + 1
             return fn(*args, **kwargs)
 
+        wrapper.__wrapped__ = fn
         return wrapper
+
+    def _fetch(self, x, copy: bool = False) -> np.ndarray:
+        """``x`` as a numpy array; a device array's fetch counts in
+        ``stats.readbacks`` (the host engine's arrays are numpy already).
+        ``copy`` returns a writable copy."""
+        if isinstance(x, jax.Array):
+            self.stats.readbacks += 1
+        return np.array(x) if copy else np.asarray(x)
+
+    def _put(self, *arrays) -> list:
+        """Upload host arrays for a serving call, counted in
+        ``stats.uploads``."""
+        self.stats.uploads += len(arrays)
+        return [jnp.asarray(a) for a in arrays]
 
     def _bind_cache(self, cache: STDDeviceCache) -> None:
         """(Re)compile the jitted serving ops against ``cache`` -- run at
@@ -427,17 +480,12 @@ class Broker:
                 jnp.asarray(np.zeros(s, np.uint32)),
                 jnp.asarray(np.zeros(s, np.uint32)),
             )
+            plan = [jnp.asarray(a) for a in self._pad_plan(None, s)]
             if self.fused and self.fused_one_call:
-                out = self._one_call_step(
-                    self.state, *self._pad_plan(None, s), *args
-                )
+                out = self._one_call_step(self.state, *plan, *args)
             elif self.fused:
                 out = self._fused_step(self.state, *args)
-                jax.block_until_ready(
-                    self._fused_fill_step(
-                        self.state, *self._pad_plan(None, s), *args
-                    )
-                )
+                jax.block_until_ready(self._fused_fill_step(self.state, *plan, *args))
             else:
                 out = self._probe(self.state, *args[:3], args[5])
                 jax.block_until_ready(
@@ -450,7 +498,7 @@ class Broker:
             jax.block_until_ready(out)
             # flush() pads a pending plan to its own bucket, so the
             # standalone fill sees the same shape ladder
-            jax.block_until_ready(self._fill(self.state, *self._pad_plan(None, s)))
+            jax.block_until_ready(self._fill(self.state, *plan))
             self._warmed_shapes.add(s)
             warmed.append(s)
         return warmed
@@ -564,6 +612,7 @@ class Broker:
         query_ids: np.ndarray,
         topics: Optional[np.ndarray] = None,
         h64: Optional[np.ndarray] = None,
+        seq: Optional[int] = None,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Serve one batch of query ids -> (values (B, V), hit mask).
 
@@ -572,7 +621,8 @@ class Broker:
         once); ``h64`` likewise short-circuits ``splitmix64`` with the
         exact hash words the cluster routed on (bit-identical by
         construction -- the high word picks the shard, the low word the
-        set).
+        set).  ``seq`` is the batch's sequence number in the trace's
+        span metadata (the cluster's; default this broker's batch count).
 
         Probes are atomic per batch: a duplicate key inside one batch is
         probed before its first occurrence commits, so it counts as a miss
@@ -601,26 +651,43 @@ class Broker:
                 "checkpoint) to keep serving"
             )
         b = len(query_ids)
-        if topics is None:
-            topics = self.topic_of(query_ids)
-        parts = np.asarray(self.cache.parts_for(np.asarray(topics)), np.int32)
-        if h64 is None:
-            h64 = splitmix64(query_ids)
-        h_hi, h_lo = pack_hashes(h64)
-        h_hi, h_lo, parts = self._pad_to_bucket(h_hi, h_lo, parts)
-        min_ep, eps = self._freshness_arrays(parts)
+        bp = self.bucket.padded_len(b) if self.bucket is not None else b
+        if seq is None:
+            seq = self.stats.batches
+        with TraceAnnotation("broker.serve", shard=self.shard, seq=seq, b=b, bp=bp):
+            return self._serve(query_ids, topics, h64, seq)
+
+    def _serve(self, query_ids, topics, h64, seq):
+        b = len(query_ids)
+        with TraceAnnotation("broker.prepare", seq=seq):
+            if topics is None:
+                topics = self.topic_of(query_ids)
+            parts = np.asarray(self.cache.parts_for(np.asarray(topics)), np.int32)
+            if h64 is None:
+                h64 = splitmix64(query_ids)
+            h_hi, h_lo = pack_hashes(h64)
+            h_hi, h_lo, parts = self._pad_to_bucket(h_hi, h_lo, parts)
+            min_ep, eps = self._freshness_arrays(parts)
+            if self.fused:
+                # the fused path admits over the whole batch before the
+                # probe; pads are never admitted (the engines mask too)
+                admit = np.zeros(len(h_hi), bool)
+                admit[:b] = (
+                    np.asarray(self.admission(query_ids), bool)
+                    if self.admission is not None
+                    else True
+                )
         if self.fused:
-            out = self._serve_fused(query_ids, parts, h_hi, h_lo, min_ep, eps)
+            out = self._serve_fused(query_ids, parts, h_hi, h_lo, min_ep, eps, admit, seq)
             self._after_batch(topics)
             return out
         hit, layer, value, stale = self._probe(
-            self.state, jnp.asarray(h_hi), jnp.asarray(h_lo), jnp.asarray(parts),
-            jnp.asarray(min_ep),
+            self.state, *self._put(h_hi, h_lo, parts, min_ep)
         )
-        hit = np.asarray(hit)[:b]
-        layer = np.asarray(layer)[:b]
-        stale = np.asarray(stale)[:b]
-        values = np.array(value)[:b]  # writable copy, pads sliced off
+        hit = self._fetch(hit)[:b]
+        layer = self._fetch(layer)[:b]
+        stale = self._fetch(stale)[:b]
+        values = self._fetch(value, copy=True)[:b]  # pads sliced off
         self.stats.expired += int(stale.sum())
         swr = (
             self.freshness_spec is not None
@@ -638,25 +705,26 @@ class Broker:
 
         miss_idx = np.flatnonzero(~hit)
         if len(miss_idx):
-            if self.coalesce:
-                uniq, inverse = np.unique(query_ids[miss_idx], return_inverse=True)
-                self.stats.coalesced += len(miss_idx) - len(uniq)
-                miss_values = self._dispatch(uniq)[inverse]
-            else:
-                miss_values = self._dispatch(query_ids[miss_idx])
-            values[miss_idx] = miss_values
-            admit = (
-                self.admission(query_ids[miss_idx])
-                if self.admission is not None
-                else np.ones(len(miss_idx), bool)
-            )
-            # expired entries refresh regardless of admission (they are
-            # resident); only true misses consult the gate
-            self.stats.admitted += int((admit & ~stale[miss_idx]).sum())
-            self._commit_bucketed(
-                h_hi[miss_idx], h_lo[miss_idx], parts[miss_idx], miss_values, admit,
-                epochs=eps[miss_idx], min_epoch=min_ep[miss_idx],
-            )
+            with TraceAnnotation("broker.miss", seq=seq):
+                if self.coalesce:
+                    uniq, inverse = np.unique(query_ids[miss_idx], return_inverse=True)
+                    self.stats.coalesced += len(miss_idx) - len(uniq)
+                    miss_values = self._dispatch(uniq)[inverse]
+                else:
+                    miss_values = self._dispatch(query_ids[miss_idx])
+                values[miss_idx] = miss_values
+                admit = (
+                    self.admission(query_ids[miss_idx])
+                    if self.admission is not None
+                    else np.ones(len(miss_idx), bool)
+                )
+                # expired entries refresh regardless of admission (they
+                # are resident); only true misses consult the gate
+                self.stats.admitted += int((admit & ~stale[miss_idx]).sum())
+                self._commit_bucketed(
+                    h_hi[miss_idx], h_lo[miss_idx], parts[miss_idx], miss_values,
+                    admit, epochs=eps[miss_idx], min_epoch=min_ep[miss_idx],
+                )
         # hits refresh recency too (exact LRU semantics); a stale
         # serve-while-revalidate hit additionally carries its backend
         # refresh value into the same commit (the engines only write
@@ -718,14 +786,7 @@ class Broker:
         if min_epoch is not None:
             minep[:n] = min_epoch
         self.state = self._commit(
-            self.state,
-            jnp.asarray(h_hi),
-            jnp.asarray(h_lo),
-            jnp.asarray(parts),
-            jnp.asarray(values),
-            jnp.asarray(admit),
-            jnp.asarray(eps),
-            jnp.asarray(minep),
+            self.state, *self._put(h_hi, h_lo, parts, values, admit, eps, minep)
         )
 
     def _after_batch(self, topics: np.ndarray) -> None:
@@ -743,7 +804,7 @@ class Broker:
             self.rebalance()
 
     def _serve_fused(
-        self, query_ids, parts, h_hi, h_lo, min_ep, eps
+        self, query_ids, parts, h_hi, h_lo, min_ep, eps, admit, seq
     ) -> Tuple[np.ndarray, np.ndarray]:
         """One fused device call per batch; the request arrays may carry a
         bucket-padded tail (``len(h_hi) >= len(query_ids)``) of reserved
@@ -752,13 +813,6 @@ class Broker:
         epochs (zeros without a spec); expiry rides the same call."""
         b = len(query_ids)
         bp = len(h_hi)
-        admit = (
-            np.asarray(self.admission(query_ids), bool)
-            if self.admission is not None
-            else np.ones(b, bool)
-        )
-        if bp > b:  # pads are never admitted (belt: the engines also mask)
-            admit = np.concatenate([admit, np.zeros(bp - b, bool)])
         if self.engine == "host":
             # the broker owns its state: the previous batch's arrays are
             # consumed in place (the host-engine analogue of jit donation)
@@ -778,63 +832,34 @@ class Broker:
                     # same entry point, so every served batch is exactly
                     # ONE device dispatch and one compiled shape/bucket
                     if pending is not None and len(pending[0]) > bp:
-                        self.flush()  # plan larger than this bucket (rare)
+                        self._flush(seq)  # plan larger than this bucket (rare)
                         pending = None
-                    hit, layer, value, stale, new_state, (set_idx, wrote, way) = (
-                        self._one_call_step(
-                            self.state,
-                            *self._pad_plan(pending, bp),
-                            jnp.asarray(h_hi),
-                            jnp.asarray(h_lo),
-                            jnp.asarray(parts),
-                            jnp.asarray(admit),
-                            jnp.asarray(eps),
-                            jnp.asarray(min_ep),
-                        )
-                    )
-                    # consumed only once the call was issued against it
-                    self._pending_fill = None
-                    self.state = new_state
+                    step = self._one_call_step
                 elif pending is not None and 0 < len(pending[0]) <= bp:
                     # double-buffered fill: the previous batch's value
                     # scatter rides inside this fused call (applied before
                     # its probe), with the plan padded to this batch's
                     # bucket
-                    hit, layer, value, stale, new_state, (set_idx, wrote, way) = (
-                        self._fused_fill_step(
-                            self.state,
-                            *self._pad_plan(pending, bp),
-                            jnp.asarray(h_hi),
-                            jnp.asarray(h_lo),
-                            jnp.asarray(parts),
-                            jnp.asarray(admit),
-                            jnp.asarray(eps),
-                            jnp.asarray(min_ep),
-                        )
-                    )
-                    # the plan is consumed only once the call was issued
-                    # against it: a raise above leaves it pending, so a
-                    # retry or flush() still lands the values instead of
-                    # losing them
-                    self._pending_fill = None
-                    self.state = new_state
+                    step = self._fused_fill_step
                 else:
-                    self.flush()  # plan larger than this bucket: standalone fill
-                    hit, layer, value, stale, self.state, (set_idx, wrote, way) = (
-                        self._fused_step(
-                            self.state,
-                            jnp.asarray(h_hi),
-                            jnp.asarray(h_lo),
-                            jnp.asarray(parts),
-                            jnp.asarray(admit),
-                            jnp.asarray(eps),
-                            jnp.asarray(min_ep),
-                        )
+                    self._flush(seq)  # plan larger than this bucket: standalone fill
+                    step = self._fused_step
+                with TraceAnnotation("broker.dispatch", seq=seq):
+                    plan = () if step is self._fused_step else self._pad_plan(pending, bp)
+                    hit, layer, value, stale, new_state, (set_idx, wrote, way) = step(
+                        self.state,
+                        *self._put(*plan, h_hi, h_lo, parts, admit, eps, min_ep),
                     )
-        hit = np.asarray(hit)[:b]
-        layer = np.asarray(layer)[:b]
-        stale = np.asarray(stale)[:b]
-        values = np.array(value)  # (bp, V) writable; sliced on return
+                # the plan is consumed only once the call was issued
+                # against it: a raise above leaves it pending, so a retry
+                # or flush() still lands the values instead of losing them
+                self._pending_fill = None
+                self.state = new_state
+        with TraceAnnotation("broker.readback", seq=seq):
+            hit = self._fetch(hit)[:b]
+            layer = self._fetch(layer)[:b]
+            stale = self._fetch(stale)[:b]
+            values = self._fetch(value, copy=True)  # (bp, V); sliced on return
         self.stats.expired += int(stale.sum())
         swr = (
             self.freshness_spec is not None
@@ -849,68 +874,76 @@ class Broker:
             # tripwire mirroring the unfused path: stale serves under
             # policy "miss" are violations, structurally zero
             self.stats.freshness_violations += int((hit & stale).sum())
-        miss_idx = np.flatnonzero(~hit)
-        if len(miss_idx):
-            if self.coalesce:
-                uniq, inverse = np.unique(query_ids[miss_idx], return_inverse=True)
-                self.stats.coalesced += len(miss_idx) - len(uniq)
-                values[miss_idx] = self._dispatch(uniq)[inverse]
-            else:
-                values[miss_idx] = self._dispatch(query_ids[miss_idx])
-            # expired entries refresh regardless of admission (they are
-            # resident); only true misses consult the gate
-            self.stats.admitted += int((admit[miss_idx] & ~stale[miss_idx]).sum())
-        # serve-stale-while-revalidate: answer stale hits from the cached
-        # value *now*, fetch the fresh one too, and route it into the
-        # reserved slot via the deferred fill -- the caller sees bounded
-        # staleness instead of backend latency
-        fill_vals = values
-        if swr:
-            reval_idx = np.flatnonzero(hit & stale)
-            if len(reval_idx):
-                self.stats.stale_served += len(reval_idx)
-                uniq, inverse = np.unique(query_ids[reval_idx], return_inverse=True)
-                self.stats.revalidations += len(uniq)
-                fill_vals = values.copy()
-                fill_vals[reval_idx] = self._dispatch(uniq)[inverse]
-        # deferred fill: scatter results into the slots the fused call
-        # reserved (fresh hit refreshes kept their values; inserts and
-        # stale revalidations write)
-        wrote_np = np.asarray(wrote)
-        if wrote_np.any():
-            if self.engine == "host":
-                self.state = self.cache.fill_values_host(
-                    self.state, set_idx, wrote_np, way, fill_vals, inplace=True
-                )
-            elif self.defer_fill:
-                # double-buffer: hold the compressed plan; it lands inside
-                # the next fused call (or flush()) -- key/stamp words are
-                # already committed, only values lag, and the next probe
-                # reads them post-fill by construction
-                sel = np.flatnonzero(wrote_np)
-                with self._fill_lock:
-                    self._pending_fill = (
-                        np.asarray(set_idx)[sel],
-                        np.asarray(way)[sel],
-                        fill_vals[sel],
-                    )
-            else:
-                self.state = self._fill(
-                    self.state, set_idx, wrote, way, jnp.asarray(fill_vals)
-                )
+        with TraceAnnotation("broker.miss", seq=seq):
+            miss_idx = np.flatnonzero(~hit)
+            if len(miss_idx):
+                if self.coalesce:
+                    uniq, inverse = np.unique(query_ids[miss_idx], return_inverse=True)
+                    self.stats.coalesced += len(miss_idx) - len(uniq)
+                    values[miss_idx] = self._dispatch(uniq)[inverse]
+                else:
+                    values[miss_idx] = self._dispatch(query_ids[miss_idx])
+                # expired entries refresh regardless of admission (they
+                # are resident); only true misses consult the gate
+                self.stats.admitted += int((admit[miss_idx] & ~stale[miss_idx]).sum())
+            # serve-stale-while-revalidate: answer stale hits from the
+            # cached value *now*, fetch the fresh one too, and route it
+            # into the reserved slot via the deferred fill -- the caller
+            # sees bounded staleness instead of backend latency
+            fill_vals = values
+            if swr:
+                reval_idx = np.flatnonzero(hit & stale)
+                if len(reval_idx):
+                    self.stats.stale_served += len(reval_idx)
+                    uniq, inverse = np.unique(query_ids[reval_idx], return_inverse=True)
+                    self.stats.revalidations += len(uniq)
+                    fill_vals = values.copy()
+                    fill_vals[reval_idx] = self._dispatch(uniq)[inverse]
+        with TraceAnnotation("broker.fill_plan", seq=seq):
+            self._plan_fill(set_idx, wrote, way, fill_vals)
         self.stats.requests += b
         self.stats.hits += int(hit.sum())
         self.stats.static_hits += int(((layer == 0) & hit).sum())
         self.stats.topic_hits += int(((layer == 1) & hit).sum())
         return values[:b], hit
 
+    def _plan_fill(self, set_idx, wrote, way, fill_vals) -> None:
+        """Deferred fill: scatter the backend's results into the slots the
+        fused call reserved (fresh hit refreshes kept their values;
+        inserts and stale revalidations write).  Where the set indices
+        come back to the host anyway, the replay's depth is counted from
+        them: the largest number of the padded batch's requests in one
+        set."""
+        wrote_np = self._fetch(wrote)
+        if not wrote_np.any():
+            return
+        if self.engine == "device" and not self.defer_fill:
+            self.state = self._fill(
+                self.state, set_idx, wrote, way, *self._put(fill_vals)
+            )
+            return
+        set_np = self._fetch(set_idx)
+        self.stats.replay_rounds += int(np.bincount(set_np).max())
+        if self.engine == "host":
+            self.state = self.cache.fill_values_host(
+                self.state, set_np, wrote_np, way, fill_vals, inplace=True
+            )
+            return
+        # double-buffer: hold the compressed plan; it lands inside the
+        # next fused call (or flush()) -- key/stamp words are already
+        # committed, only values lag, and the next probe reads them
+        # post-fill by construction
+        sel = np.flatnonzero(wrote_np)
+        with self._fill_lock:
+            self._pending_fill = (set_np[sel], self._fetch(way)[sel], fill_vals[sel])
+
     def _pad_plan(self, pending, bp: int):
         """Pad a compressed pending-fill plan up to ``bp`` entries (pads
         carry ``wrote=False``) in :meth:`STDDeviceCache.fill_values`
-        argument order.  ``pending=None`` builds the all-inert plan the
-        one-call entry point takes when nothing is pending -- same
-        shapes/dtypes, zero writes -- so an idle serve compiles no extra
-        shape."""
+        argument order, as host arrays.  ``pending=None`` builds the
+        all-inert plan the one-call entry point takes when nothing is
+        pending -- same shapes/dtypes, zero writes -- so an idle serve
+        compiles no extra shape."""
         if pending is None:
             f_set = np.zeros(0, np.int32)
             f_way = np.zeros(0, np.int32)
@@ -926,12 +959,7 @@ class Broker:
         wrote_p[:n] = True
         vals_p = np.zeros((bp, f_vals.shape[1]), np.int32)
         vals_p[:n] = f_vals
-        return (
-            jnp.asarray(set_p),
-            jnp.asarray(wrote_p),
-            jnp.asarray(way_p),
-            jnp.asarray(vals_p),
-        )
+        return set_p, wrote_p, way_p, vals_p
 
     def flush(self) -> None:
         """Apply a double-buffered pending value fill to the state now.
@@ -942,13 +970,21 @@ class Broker:
         safe to overlap with a fused serve (the handoff lock makes the
         plan land exactly once whichever side consumes it).
         """
+        self._flush()
+
+    def _flush(self, seq: Optional[int] = None) -> None:
+        """:meth:`flush`; ``seq`` is the batch that needed it (traces)."""
         with self._fill_lock:
             pending = self._pending_fill
             if pending is None:
                 return
-            n = len(pending[0])
-            bp = self.bucket.padded_len(n) if self.bucket is not None else n
-            self.state = self._fill(self.state, *self._pad_plan(pending, bp))
+            meta = {} if seq is None else {"seq": seq}
+            with TraceAnnotation("broker.flush", **meta):
+                n = len(pending[0])
+                bp = self.bucket.padded_len(n) if self.bucket is not None else n
+                self.state = self._fill(
+                    self.state, *self._put(*self._pad_plan(pending, bp))
+                )
             # consumed only after the fill was issued: a raise above keeps
             # the plan pending, so a retrying caller (resilient dispatch)
             # flushes again instead of silently losing the values
@@ -1165,14 +1201,17 @@ class Broker:
                 # checkpoint's)
                 for k in ("static_hi", "static_lo", "static_value"):
                     state_template[k] = self.state[k]
+        # a checkpoint that predates the tracker or the transfer counters
+        # restores everything else: the tracker cold-starts from its zero
+        # counts, the counters read 0
         stats_tree = self._stats_tree()
-        if (
-            "topic_counts" in stats_tree
-            and ckpt_lib.load_leaf(ckpt_dir, step, "stats/topic_counts") is None
-        ):
-            # checkpoint predates the tracker: restore everything else and
-            # let the tracker cold-start from its zero counts
-            del stats_tree["topic_counts"]
+        saved_leaves = ckpt_lib.leaf_names(ckpt_dir, step)
+        absent = [
+            k for k in ("topic_counts",) + _LATER_COUNTERS
+            if k in stats_tree and f"stats/{k}" not in saved_leaves
+        ]
+        for k in absent:
+            del stats_tree[k]
         tree_like = {"cache": state_template, "stats": stats_tree}
         if (
             self.freshness is not None
@@ -1196,6 +1235,9 @@ class Broker:
                 self.tracker.load(np.asarray(v, np.float64))
             else:
                 setattr(self.stats, k, int(v))
+        for k in absent:
+            if k != "topic_counts":
+                setattr(self.stats, k, 0)
         return got
 
     def _check_allocation_compatible(self, saved_cfg: DeviceCacheConfig) -> None:

@@ -48,6 +48,14 @@ are *injected* per shard with :meth:`inject_shard_faults`
 (:class:`repro.loadgen.inject.FaultInjectSpec`); the open-loop harness
 drives the virtual clock through :meth:`advance_time` so whole fault
 episodes replay bit-identically.
+
+Tracing: every batch gets a sequence number, carried as the metadata
+``seq`` of its ``jax.profiler.TraceAnnotation`` spans -- ``cluster.serve``
+(``seq``, ``requests``) around ``cluster.route`` (topic lookup, hash,
+shard split) and one ``broker.serve`` per shard slice (the broker's own
+phase spans below it; see :mod:`repro.serving.broker`).  Under
+:meth:`serve_async` the route is traced at enqueue and each drain step
+is a ``cluster.serve`` span carrying its first batch's ``seq``.
 """
 from __future__ import annotations
 
@@ -61,6 +69,7 @@ from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..freshness import FreshnessRuntime
 from ..train import checkpoint as ckpt_lib
@@ -105,16 +114,17 @@ class _ShardCall:
     between attempts while other shards' calls proceed."""
 
     __slots__ = (
-        "i", "query_ids", "topics", "h64", "on_done",
+        "i", "query_ids", "topics", "h64", "on_done", "batch",
         "attempt", "seq", "err", "not_before",
     )
 
-    def __init__(self, i, query_ids, topics, h64, on_done):
+    def __init__(self, i, query_ids, topics, h64, on_done, batch=None):
         self.i = i
         self.query_ids = query_ids
         self.topics = topics
         self.h64 = h64
         self.on_done = on_done
+        self.batch = batch  # the (first) batch's sequence number, for traces
         self.attempt = 0
         self.seq: Optional[int] = None
         self.err: Optional[Exception] = None
@@ -130,8 +140,9 @@ class ClusterFuture:
     The future is not thread-safe -- it is a pipelining handle for the
     submitting thread, not a synchronization primitive."""
 
-    def __init__(self, cluster: "Cluster", n: int):
+    def __init__(self, cluster: "Cluster", n: int, seq: Optional[int] = None):
         self._cluster = cluster
+        self.seq = seq  # the batch's sequence number
         self.values = np.zeros((n, cluster.spec.value_dim), np.int32)
         self.hit = np.zeros(n, bool)
         self._remaining = 0  # shard slices still queued or in flight
@@ -162,7 +173,11 @@ class Cluster:
             )
         self.spec = spec
         self.brokers = list(brokers)
+        for i, b in enumerate(self.brokers):
+            b.shard = i
         self.topic_of = topic_of
+        #: batches submitted so far: the next batch's sequence number
+        self._batches = 0
         # scatter-gather pool: shards are independent, so their serves can
         # overlap -- but threads only pay off when shard work releases the
         # GIL (device engines queue async work; slow backends block in
@@ -323,33 +338,41 @@ class Cluster:
         self._check_open()
         query_ids = np.asarray(query_ids)
         b = len(query_ids)
-        topics, h64, shard = self._route(query_ids)
-        values = np.zeros((b, self.spec.value_dim), np.int32)
-        hit = np.zeros(b, bool)
-        if shard is None:
-            if b:
-                v, h = self._serve_shard(0, query_ids, topics)
-                values[:], hit[:] = v, h
-            return values, hit
-        calls = []
-        for i in range(len(self.brokers)):
-            idx = np.flatnonzero(shard == i)
-            if not len(idx):
-                continue
+        seq = self._next_seq()
+        with TraceAnnotation("cluster.serve", seq=seq, requests=b):
+            with TraceAnnotation("cluster.route", seq=seq):
+                topics, h64, shard = self._route(query_ids)
+            values = np.zeros((b, self.spec.value_dim), np.int32)
+            hit = np.zeros(b, bool)
+            if shard is None:
+                if b:
+                    v, h = self._serve_shard(0, query_ids, topics, batch=seq)
+                    values[:], hit[:] = v, h
+                return values, hit
+            calls = []
+            for i in range(len(self.brokers)):
+                idx = np.flatnonzero(shard == i)
+                if not len(idx):
+                    continue
 
-            def on_done(v, h, idx=idx):
-                values[idx] = v
-                hit[idx] = h
+                def on_done(v, h, idx=idx):
+                    values[idx] = v
+                    hit[idx] = h
 
-            calls.append(
-                _ShardCall(
-                    i, query_ids[idx],
-                    None if topics is None else topics[idx],
-                    h64[idx], on_done,
+                calls.append(
+                    _ShardCall(
+                        i, query_ids[idx],
+                        None if topics is None else topics[idx],
+                        h64[idx], on_done, batch=seq,
+                    )
                 )
-            )
-        self._execute(calls)
-        return values, hit
+            self._execute(calls)
+            return values, hit
+
+    def _next_seq(self) -> int:
+        seq = self._batches
+        self._batches += 1
+        return seq
 
     # -- pipelined async dispatch ------------------------------------------
 
@@ -375,10 +398,11 @@ class Cluster:
         """
         self._check_open()
         query_ids = np.asarray(query_ids)
-        fut = ClusterFuture(self, len(query_ids))
+        fut = ClusterFuture(self, len(query_ids), seq=self._next_seq())
         if len(query_ids) == 0:
             return fut
-        topics, h64, shard = self._route(query_ids)
+        with TraceAnnotation("cluster.route", seq=fut.seq):
+            topics, h64, shard = self._route(query_ids)
         if shard is None:
             self._queues[0].append(
                 (fut, slice(None), query_ids, topics, None)
@@ -432,7 +456,13 @@ class Cluster:
                 nreq += len(seg[2])
                 segs.append(seg)
             calls.append(self._fused_call(i, segs))
-        self._execute(calls)
+        if not calls:
+            return
+        with TraceAnnotation(
+            "cluster.serve", seq=min(c.batch for c in calls),
+            requests=sum(len(c.query_ids) for c in calls),
+        ):
+            self._execute(calls)
 
     def _fused_call(self, i: int, segs: list) -> _ShardCall:
         """Concatenate queued slices into one shard call whose completion
@@ -445,7 +475,7 @@ class Cluster:
                 fut.hit[idx] = h
                 fut._remaining -= 1
 
-            return _ShardCall(i, qids, topics, h64, on_done)
+            return _ShardCall(i, qids, topics, h64, on_done, batch=fut.seq)
         qids = np.concatenate([s[2] for s in segs])
         topics = (
             np.concatenate([s[3] for s in segs])
@@ -498,7 +528,9 @@ class Cluster:
                 fut.hit[idx] = h[lo:hi]
                 fut._remaining -= 1
 
-        return _ShardCall(i, call_qids, call_topics, call_h64, on_done)
+        return _ShardCall(
+            i, call_qids, call_topics, call_h64, on_done, batch=segs[0][0].seq
+        )
 
     # -- resilient dispatch ------------------------------------------------
 
@@ -547,17 +579,17 @@ class Cluster:
         """Per-shard health machines (None without a ResilienceSpec)."""
         return self._health
 
-    def _call_shard(self, i: int, query_ids, topics, h64=None):
+    def _call_shard(self, c: _ShardCall):
         """One dispatch attempt: injected faults fire first (they model
         the shard being unreachable -- the broker is never entered)."""
-        inj = self._injectors[i]
+        inj = self._injectors[c.i]
         if inj is not None:
-            inj.check(self._clock(), n=len(query_ids))
-        return self.brokers[i].serve(query_ids, topics, h64=h64)
+            inj.check(self._clock(), n=len(c.query_ids))
+        return self.brokers[c.i].serve(c.query_ids, c.topics, h64=c.h64, seq=c.batch)
 
-    def _serve_shard(self, i: int, query_ids, topics, h64=None):
+    def _serve_shard(self, i: int, query_ids, topics, h64=None, batch=None):
         """Serve one shard slice to completion (inline retries)."""
-        call = _ShardCall(i, query_ids, topics, h64, None)
+        call = _ShardCall(i, query_ids, topics, h64, None, batch=batch)
         out = self._attempt(call)
         while out is _RETRY:
             delay = call.not_before - time.monotonic()
@@ -636,7 +668,7 @@ class Cluster:
         cooperative-timeout detection never depends on wall-clock noise
         and fault episodes replay bit-identically."""
         if self._health is None:
-            return self._call_shard(c.i, c.query_ids, c.topics, c.h64)
+            return self._call_shard(c)
         res = self.spec.resilience
         i = c.i
         h = self._health[i]
@@ -659,7 +691,7 @@ class Cluster:
         attempts = res.max_retries + 1
         try:
             t_start = self._clock()
-            out = self._call_shard(i, c.query_ids, c.topics, c.h64)
+            out = self._call_shard(c)
         except Exception as e:
             c.err = e
             h.record_failure(self._clock())

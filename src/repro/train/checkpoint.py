@@ -159,6 +159,13 @@ def latest_verified_step(ckpt_dir: str) -> Optional[int]:
     return None
 
 
+def leaf_names(ckpt_dir: str, step: int) -> set:
+    """The path keys of every leaf saved in ``step``."""
+    target = os.path.join(ckpt_dir, f"step_{step:010d}")
+    with np.load(os.path.join(target, "arrays.npz")) as data:
+        return set(data.files)
+
+
 def load_leaf(
     ckpt_dir: str, step: int, key: str, verify: bool = True
 ) -> Optional[np.ndarray]:

@@ -5,6 +5,8 @@ from .ops import (
     pack_words,
     plan_segments,
     probe_and_commit_op,
+    replay_lane_rounds,
+    replay_schedule,
     resolve_conflicts,
     serve_fused_op,
     unpack_epoch,
@@ -21,6 +23,8 @@ __all__ = [
     "plan_segments",
     "probe_and_commit_op",
     "probe_and_commit_ref",
+    "replay_lane_rounds",
+    "replay_schedule",
     "resolve_conflicts",
     "serve_fused_op",
     "serve_fused_ref",

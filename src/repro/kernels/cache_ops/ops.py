@@ -7,9 +7,12 @@ with three data-parallel phases:
 1. **plan** -- stable-sort the batch by set index; each run of equal sets
    is a *segment* whose requests must apply in arrival order;
 2. **resolve** -- gather one packed row of key/stamp words per segment
-   and replay round j = 0, 1, ... across *all* segments at once: round j
+   and replay round j = 0, 1, ... across the segments at once: round j
    applies every segment's j-th request.  The loop runs max-segment-length
-   times, not B times;
+   times, not B times, and only over the segments still active: lanes
+   hold the segments longest first, and round j runs on a prefix that
+   shrinks with j (:func:`replay_schedule`), so a batch costs about
+   ``B * (log2(max seg_len) + 1)`` lane-rounds, not ``B * max seg_len``;
 3. **scatter** -- write each resolved row back in a single scatter.
 
 State layout: the per-slot key_hi / key_lo / stamp / epoch words live in
@@ -46,6 +49,7 @@ an eviction -- shape-bucketed serving pads ragged batches with them.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple, Union
 
 import jax
@@ -144,6 +148,44 @@ def plan_segments(
     return order, seg_id, leader, seg_len, seg_set
 
 
+#: narrowest lane width of the conflict replay: one lane tile
+REPLAY_LANE_FLOOR = 128
+
+
+def replay_schedule(b: int) -> Tuple[Tuple[int, int, int], ...]:
+    """The conflict replay's lane tiers for a batch of ``b`` requests:
+    ``(start, stop, width)`` per tier, rounds ``[start, stop)`` on the
+    first ``width`` lanes.
+
+    In round j only segments longer than j are active, at most
+    ``b // (j + 1)`` of them.  Tier t covers rounds ``[2^t - 1,
+    2^(t+1) - 1)`` at width ``b >> t`` until the width reaches the
+    128-lane floor; the last tier runs every remaining round, up to
+    ``b``, at that floor.  For ``b <= 128`` it is one tier of every round
+    at full width.
+    """
+    floor = min(b, REPLAY_LANE_FLOOR)
+    tiers = []
+    t = 0
+    while True:
+        start, width = (1 << t) - 1, max(b >> t, floor)
+        if width == floor:
+            tiers.append((start, b, width))
+            return tuple(tiers)
+        tiers.append((start, (1 << (t + 1)) - 1, width))
+        t += 1
+
+
+def replay_lane_rounds(b: int, depth: int) -> int:
+    """Lane-rounds :func:`replay_schedule` runs on a batch of ``b`` whose
+    deepest segment holds ``depth`` requests (``b * depth`` for
+    ``b <= 128``)."""
+    return sum(
+        max(0, min(stop, depth) - start) * width
+        for start, stop, width in replay_schedule(b)
+    )
+
+
 def resolve_conflicts(
     rows_hi: jnp.ndarray,  # (B, W) one pristine row per segment
     rows_lo: jnp.ndarray,
@@ -161,28 +203,35 @@ def resolve_conflicts(
     clock: jnp.ndarray,
     seg_id: jnp.ndarray = None,  # (B,) sorted-position -> segment (optional)
 ):
-    """Pure-jnp rounds loop: replay round j across all segments at once.
+    """Pure-jnp rounds loop: replay round j across the active segments.
 
     Bit-exact with the sequential fori_loop commit: within a segment the
     evolving row sees exactly the same match / argmin-eviction / stamp /
     staleness sequence, and segments never share a set so rounds are
-    independent.
+    independent -- and so is the order of the segments among themselves.
 
-    The loop carries only what actually evolves: the packed rows plus the
-    write plan (``wrote``/``way``) -- and the loop body is scatter-free.
-    The probe outputs (``pre_hit``/``pre_way``/``pre_stale``/``pre_epoch``)
-    are pure functions of the *pristine* rows, so
-    :func:`probe_and_commit_op` computes them in one vectorized pass; and
-    each sorted position is written in exactly one round (its rank within
-    its segment), so the write plan lands through a per-segment gather
-    masked by rank instead of a per-round scatter.  On XLA CPU scatters
-    price at ~170ns/index, which made the per-query cost *flat* in batch
-    size (~2 scatters x rounds each) and kept B=4096 exactly as slow per
-    query as B=256 -- the ``cache_commit_vec_xla`` anomaly; gathers are an
-    order of magnitude cheaper and let large batches amortize.
+    Lanes hold the segments longest first (one argsort of ``seg_len``;
+    padded segments last), so the segments active in round j are a
+    prefix of the lanes.  The rounds run in the tiers of
+    :func:`replay_schedule`: tier t runs on the first ``b >> t`` lanes
+    (never fewer than 128), each tier a loop clipped to ``max(seg_len)``
+    and skipped whole when it would run no round.  A batch therefore
+    costs about ``B * (log2(max seg_len) + 1)`` lane-rounds instead of
+    ``B * max(seg_len)``, and nothing inside a round indexes more than
+    its tier's width: each lane gathers one packed row of its request's
+    fields, and the round's write plan (``way``, ``refresh``) lands in
+    a per-round record by a dynamic slice update.  After the loop one
+    gather maps every sorted position to its round's record and one
+    inverse permutation returns the rows to segment order.
 
-    ``seg_id`` (from :func:`plan_segments`) enables the gather-based plan;
-    when omitted it is recomputed from ``leader``/``seg_len``.
+    The loop body is scatter-free: on XLA CPU scatters price at ~170
+    ns/index, and per-round scatters once kept B=4096 exactly as slow per
+    query as B=256.  The probe outputs are pure functions of the pristine
+    rows, so :func:`probe_and_commit_op` computes them in one vectorized
+    pass outside the loop.
+
+    ``seg_id`` (from :func:`plan_segments`) is recomputed from
+    ``leader``/``seg_len`` when omitted.
     """
     b = rows_hi.shape[0]
     if seg_id is None:
@@ -191,34 +240,67 @@ def resolve_conflicts(
             jnp.where(seg_len > 0, 1, 0), mode="drop"
         )
         seg_id = jnp.cumsum(starts[:b]) - 1
-    rank = jnp.arange(b, dtype=jnp.int32) - leader[seg_id]
-
-    def body(j, carry):
-        r_hi, r_lo, r_st, r_ep, wr, wy = carry
-        idx = jnp.minimum(leader + j, b - 1)
-        act = j < seg_len
-        r_hi, r_lo, r_st, r_ep, is_hit, way, do_write, refresh = conflict_round(
-            r_hi, r_lo, r_st, r_ep, s_hi[idx], s_lo[idx], s_admit[idx],
-            s_static[idx], s_epoch[idx], s_minep[idx],
-            clock + 1 + s_pos[idx], act,
-        )
-        # position p's plan was computed this round iff its in-segment
-        # rank is j: select it from its segment's lane (gather + where,
-        # no scatter)
-        sel = rank == j
-        wr = jnp.where(sel, refresh[seg_id], wr)
-        wy = jnp.where(sel, way[seg_id], wy)
-        return r_hi, r_lo, r_st, r_ep, wr, wy
-
-    init = (
-        rows_hi,
-        rows_lo,
-        rows_st,
-        rows_ep,
-        jnp.zeros(b, bool),
-        jnp.zeros(b, jnp.int32),
+    arange = jnp.arange(b, dtype=jnp.int32)
+    lane = jnp.argsort(-seg_len).astype(jnp.int32)  # stable, longest first
+    lane_of_seg = jnp.zeros(b, jnp.int32).at[lane].set(arange)
+    rows = pack_words(rows_hi, rows_lo, rows_st, rows_ep)[lane]
+    l_leader, l_len = leader[lane], seg_len[lane]
+    # one packed row of request fields per sorted position, so a lane
+    # gathers one row per round instead of seven scalars
+    req = jnp.stack(
+        [
+            s_hi, s_lo, jax.lax.bitcast_convert_type(clock + 1 + s_pos, jnp.uint32),
+            s_admit.astype(jnp.uint32), s_static.astype(jnp.uint32),
+            s_epoch, s_minep,
+        ],
+        axis=1,
     )
-    return jax.lax.fori_loop(0, jnp.max(seg_len), body, init)
+    depth = jnp.max(seg_len)
+    tiers = replay_schedule(b)
+    # round j of a tier records its lanes' plan at off + (j - start) * width
+    offs = np.cumsum([0] + [(stop - start) * width for start, stop, width in tiers]).tolist()
+
+    def run_tier(start, stop, width, off, carry):
+        rows, rec = carry
+        lead, active_len = l_leader[:width], l_len[:width]
+
+        def body(j, c):
+            r_hi, r_lo, r_st, r_ep, rec = c
+            q = req[jnp.minimum(lead + j, b - 1)]
+            hi, lo, stamp, admit, static, ep, minep = (q[:, k] for k in range(7))
+            r_hi, r_lo, r_st, r_ep, _, way, _, refresh = conflict_round(
+                r_hi, r_lo, r_st, r_ep, hi, lo, admit != 0, static != 0, ep, minep,
+                jax.lax.bitcast_convert_type(stamp, jnp.int32), j < active_len,
+            )
+            code = way * 2 + refresh.astype(jnp.int32)
+            rec = jax.lax.dynamic_update_slice(rec, code, (off + (j - start) * width,))
+            return r_hi, r_lo, r_st, r_ep, rec
+
+        sub = rows[:width]
+        init = (*unpack_words(sub), unpack_epoch(sub), rec)
+        *sub, rec = jax.lax.fori_loop(start, jnp.minimum(stop, depth), body, init)
+        return rows.at[:width].set(pack_words(*sub)), rec
+
+    carry = (rows, jnp.zeros(offs[-1], jnp.int32))
+    for (start, stop, width), off in zip(tiers, offs):
+        tier = functools.partial(run_tier, start, stop, width, off)
+        if start == 0:
+            carry = tier(carry)
+        else:  # no round of this tier when no segment is that deep
+            carry = jax.lax.cond(start < depth, tier, lambda c: c, carry)
+    rows, rec = carry
+
+    # sorted position p ran in round rank(p), on its segment's lane
+    seg_lead, p_lane = leader[seg_id], lane_of_seg[seg_id]
+    rank = arange - seg_lead
+    slot = jnp.zeros(b, jnp.int32)
+    for (start, stop, width), off in zip(tiers, offs):
+        in_t = (rank >= start) & (rank < stop)
+        slot = jnp.where(in_t, off + (rank - start) * width + p_lane, slot)
+    code = rec[slot]
+    rows = rows[lane_of_seg]
+    r_hi, r_lo, r_st = unpack_words(rows)
+    return r_hi, r_lo, r_st, unpack_epoch(rows), (code & 1) != 0, code >> 1
 
 
 def _pad(x: jnp.ndarray, target: int, value=0):

@@ -49,7 +49,8 @@ fill's plan), plus ``broker.flush`` for a standalone fill.  They land in
 the same trace, on the same clock, as the device's operations, and cost
 one check each when no profiler session is active.  ``BrokerStats``
 counts the transfers behind them (``readbacks``, ``uploads``) and the
-conflict replay's depth (``replay_rounds``).
+conflict replay's depth (``replay_rounds``) and width
+(``replay_lane_rounds``).
 
 Fault tolerance: `checkpoint` / `restore` snapshot the full cache state
 atomically (repro.train.checkpoint); a broker can restart mid-stream and
@@ -72,6 +73,7 @@ from jax.profiler import TraceAnnotation
 from ..core.alloc import allocation_divergence
 from ..core.spec import CacheSpec
 from ..freshness import FreshnessRuntime, FreshnessSpec
+from ..kernels.cache_ops import replay_lane_rounds
 from ..train import checkpoint as ckpt_lib
 from . import autotune
 from .device_cache import (
@@ -144,6 +146,12 @@ class BrokerStats:
     #: plan fetches -- on batches that wrote, on the host engine and the
     #: deferred-fill device path
     replay_rounds: int = 0
+    #: conflict-replay lane-rounds, summed over the same batches: what
+    #: the tiered replay runs at the padded length and that depth
+    #: (``cache_ops.replay_lane_rounds``).  ``bp * replay_rounds`` is what
+    #: a loop over all ``bp`` lanes runs (equal for ``bp <= 128``), so
+    #: the ratio says how far the tiers narrowed the replay
+    replay_lane_rounds: int = 0
     #: the online popularity tracker's state: exponentially-decayed served
     #: request counts per tracked topic (sorted id order) + a trailing
     #: no-topic bucket; shares memory with ``Broker.tracker`` and is None
@@ -156,7 +164,7 @@ class BrokerStats:
 
 
 #: counters newer than some checkpoints: a restore reads them as 0
-_LATER_COUNTERS = ("readbacks", "uploads", "replay_rounds")
+_LATER_COUNTERS = ("readbacks", "uploads", "replay_rounds", "replay_lane_rounds")
 
 Backend = Callable[[np.ndarray], np.ndarray]  # query ids -> values (B, V)
 
@@ -913,7 +921,7 @@ class Broker:
         inserts and stale revalidations write).  Where the set indices
         come back to the host anyway, the replay's depth is counted from
         them: the largest number of the padded batch's requests in one
-        set."""
+        set, and the lane-rounds the tiered replay runs at that depth."""
         wrote_np = self._fetch(wrote)
         if not wrote_np.any():
             return
@@ -923,7 +931,9 @@ class Broker:
             )
             return
         set_np = self._fetch(set_idx)
-        self.stats.replay_rounds += int(np.bincount(set_np).max())
+        depth = int(np.bincount(set_np).max())
+        self.stats.replay_rounds += depth
+        self.stats.replay_lane_rounds += replay_lane_rounds(len(set_np), depth)
         if self.engine == "host":
             self.state = self.cache.fill_values_host(
                 self.state, set_np, wrote_np, way, fill_vals, inplace=True

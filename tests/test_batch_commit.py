@@ -19,8 +19,17 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.core import NO_TOPIC, LRUCache, STDCache
-from repro.kernels.cache_ops import pack_words, probe_and_commit_op, unpack_words
-from repro.kernels.cache_ops.ref import probe_and_commit_ref
+from repro.kernels.cache_ops import (
+    PAD_HI,
+    PAD_LO,
+    pack_words,
+    probe_and_commit_op,
+    replay_lane_rounds,
+    replay_schedule,
+    serve_fused_op,
+    unpack_words,
+)
+from repro.kernels.cache_ops.ref import probe_and_commit_ref, serve_fused_ref
 from repro.serving import (
     Broker,
     DeviceCacheConfig,
@@ -269,3 +278,142 @@ def test_broker_batch1_matches_exact_simulator(use_kernel, engine, n_req):
         assert bool(hit[0]) == expect.hit, f"request {i} (key {q}) diverged"
         assert values[0, 0] == q
     assert broker.stats.hits > 0 and broker.stats.hits < broker.stats.requests
+
+
+# -- the tiered conflict replay ----------------------------------------------
+
+_W, _V = 8, 3
+
+
+def _segments(case, b):
+    """Segment lengths (one set each) of a crafted batch; the rest of the
+    batch lands in sets of its own."""
+    if case == "one_set":
+        return [b]
+    if case == "distinct":
+        return []
+    if case == "tier_edges":
+        # lengths at every tier edge 2^t - 2, 2^t - 1, 2^t, and past the
+        # 128-lane floor, as far as the batch holds them
+        want = [n for t in range(1, 9) for n in ((1 << t) - 2, (1 << t) - 1, 1 << t)]
+        lens = []
+        for n in sorted({n for n in want + [129, 200] if n > 0}, reverse=True):
+            if sum(lens) + n <= b:
+                lens.append(n)
+        return lens
+    return [max(b // 2, 1)]  # one deep segment (hot key, static, fresh)
+
+
+def _crafted(case, b, seed):
+    """State and one batch of ``b`` for an exactness case (numpy)."""
+    rng = np.random.default_rng(seed)
+    lens = _segments(case, b)
+    n_sets = len(lens) + (b - sum(lens)) + 3
+    sets = rng.permutation(n_sets)
+    set_idx = np.concatenate(
+        [np.full(n, sets[i]) for i, n in enumerate(lens)]
+        + [sets[len(lens) : len(lens) + b - sum(lens)]]
+    ).astype(np.int32)
+    # a small key pool per set, so that rounds hit, miss and evict
+    pool = rng.integers(1, 2**31, size=(n_sets, 12)).astype(np.uint32)
+    pick = rng.integers(0, 12, size=b)
+    h_hi = pool[set_idx, pick]
+    h_lo = (h_hi * np.uint32(2654435761)) | np.uint32(1)
+    admit = rng.random(b) < 0.7
+    static = np.zeros(b, bool)
+    deep = set_idx == set_idx[0]
+    if case in ("hot_key_admit", "hot_key_no_admit"):
+        h_hi[deep], h_lo[deep] = h_hi[0], h_lo[0]
+        admit[deep] = case == "hot_key_admit"
+    if case == "static_and_pads":
+        static = deep & (rng.random(b) < 0.3)
+        pads = deep & ~static & (rng.random(b) < 0.25)
+        h_hi[pads], h_lo[pads] = PAD_HI, PAD_LO
+    order = rng.permutation(b)  # segments interleave in arrival order
+    req = [x[order] for x in (h_hi, h_lo, set_idx, admit, static)]
+    occ = rng.random((n_sets, _W)) < 0.6
+    key_hi = np.where(occ, pool[np.arange(n_sets)[:, None], rng.integers(0, 12, (n_sets, _W))], 0)
+    state = dict(
+        key_hi=key_hi.astype(np.uint32),
+        key_lo=np.where(occ, (key_hi * np.uint32(2654435761)) | np.uint32(1), 0).astype(np.uint32),
+        stamp=rng.integers(0, 1000, (n_sets, _W)).astype(np.int32),
+        epoch=rng.integers(0, 6, (n_sets, _W)).astype(np.uint32),
+        value=rng.integers(0, 100, (n_sets, _W, _V)).astype(np.int32),
+    )
+    epochs = rng.integers(0, 9, b).astype(np.uint32)
+    min_epoch = (rng.integers(0, 5, b) if case == "fresh" else np.zeros(b)).astype(np.uint32)
+    fill = (
+        rng.integers(0, n_sets, b).astype(np.int32), rng.random(b) < 0.3,
+        rng.integers(0, _W, b).astype(np.int32), rng.integers(0, 100, (b, _V)).astype(np.int32),
+    )
+    return state, req, epochs, min_epoch, fill
+
+
+@pytest.mark.parametrize("b", [1, 7, 128, 129, 200, 256, 1000, 4096])
+def test_replay_schedule_covers_every_round_wide_enough(b):
+    """Tiers cover rounds [0, b) in order, each at least as wide as the
+    segments that can still be active (``b // (round + 1)``), narrowing
+    to the 128-lane floor; at 4096 the lane-rounds are those of the
+    design (deepest segment 1, 3, 7, 19, 31, 65, 100)."""
+    tiers = replay_schedule(b)
+    assert tiers[0][0] == 0 and tiers[-1][1] == b
+    assert all(a[1] == c[0] for a, c in zip(tiers, tiers[1:]))
+    for start, stop, width in tiers:
+        assert start < stop and b // (start + 1) <= width <= b
+    assert tiers[-1][2] == min(b, 128)
+    for depth in {1, min(3, b), b}:
+        assert replay_lane_rounds(b, depth) <= b * depth
+        if b <= 128:
+            assert replay_lane_rounds(b, depth) == b * depth
+    if b == 4096:
+        assert [replay_lane_rounds(b, d) for d in (1, 3, 7, 19, 31, 65, 100)] == [
+            4096, 8192, 12288, 17408, 20480, 24832, 29312,
+        ]
+
+
+_jit_probe = jax.jit(probe_and_commit_op, static_argnames=("use_kernel", "interpret"))
+_jit_serve = jax.jit(serve_fused_op, static_argnames=("use_kernel", "interpret"))
+
+_CASES = (
+    "one_set", "tier_edges", "distinct", "hot_key_admit", "hot_key_no_admit",
+    "static_and_pads", "fresh",
+)
+
+
+@pytest.mark.parametrize("b", [1, 7, 128, 129, 256, 4096])
+@pytest.mark.parametrize("case", _CASES)
+def test_tiered_replay_is_bit_exact(case, b):
+    """The jnp engine's tiered replay (probe/commit and the one-call
+    serve) equals the sequential oracles and the Pallas interpret path."""
+    state, (h_hi, h_lo, set_idx, admit, static), epochs, min_epoch, fill = _crafted(
+        case, b, seed=b
+    )
+    ks = jnp.asarray(pack_words(state["key_hi"], state["key_lo"], state["stamp"], state["epoch"]))
+    clock = jnp.int32(5000)
+    req = [jnp.asarray(x) for x in (h_hi, h_lo, set_idx, admit, static)]
+    fresh = dict(epochs=jnp.asarray(epochs), min_epoch=jnp.asarray(min_epoch))
+    ref = probe_and_commit_ref(
+        state["key_hi"], state["key_lo"], state["stamp"], h_hi, h_lo, set_idx,
+        admit, static, 5000, epoch=state["epoch"], epochs=epochs, min_epoch=min_epoch,
+    )
+    ref_ks = pack_words(ref["key_hi"], ref["key_lo"], ref["stamp"], ref["epoch"])
+    sref = serve_fused_ref(
+        state["key_hi"], state["key_lo"], state["stamp"], state["value"], h_hi, h_lo,
+        set_idx, admit, static, 5000, epoch=state["epoch"], epochs=epochs,
+        min_epoch=min_epoch, f_set_idx=fill[0], f_wrote=fill[1], f_way=fill[2],
+        f_values=fill[3],
+    )
+    keys = ("pre_hit", "pre_way", "pre_stale", "pre_epoch", "wrote", "way")
+    for use_kernel in (False, True):
+        label = f"{case}/B={b}/{'pallas' if use_kernel else 'jnp'}"
+        got = _jit_probe(ks, *req, clock, **fresh, use_kernel=use_kernel, interpret=True)
+        assert (np.asarray(got["ks"]) == ref_ks).all(), (label, "ks")
+        for k in keys:
+            assert (np.asarray(got[k]) == ref[k]).all(), (label, k)
+        got = _jit_serve(
+            ks, jnp.asarray(state["value"]), *req, clock,
+            *(jnp.asarray(x) for x in fill), **fresh, use_kernel=use_kernel, interpret=True,
+        )
+        assert (np.asarray(got["ks"]) == ref_ks).all(), (label, "serve ks")
+        for k in keys + ("value", "values"):
+            assert (np.asarray(got[k]) == sref[k]).all(), (label, "serve", k)

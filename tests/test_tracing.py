@@ -6,8 +6,9 @@ A small device-engine cluster serves a few batches under
 read back from the trace's ``/host:CPU`` plane and must form the
 documented tree, one ``broker.serve`` per shard slice, every span of a
 batch carrying the batch's ``seq``.  ``BrokerStats`` must count seven
-readbacks and ten uploads per one-call batch and the conflict replay's
-depth exactly as ``ops.plan_segments`` gives it.  The four-shard case
+readbacks and ten uploads per one-call batch, the conflict replay's
+depth exactly as ``ops.plan_segments`` gives it, and the lane-rounds
+``ops.replay_schedule`` runs at that depth.  The four-shard case
 runs in a child process on four virtual CPU devices.
 """
 from __future__ import annotations
@@ -27,7 +28,7 @@ import numpy as np
 import pytest
 
 from repro.core import CacheSpec, VecLog, VecStats
-from repro.kernels.cache_ops.ops import plan_segments
+from repro.kernels.cache_ops.ops import plan_segments, replay_lane_rounds
 from repro.serving import (
     Broker,
     BucketSpec,
@@ -175,7 +176,7 @@ def test_counters_per_one_call_batch(tmp_path):
     assert counts["replay_rounds"] >= 4
 
 
-def _broker(engine="device"):
+def _broker(engine="device", microbatch=BATCH):
     """A broker on one shard's worth of a small stream, and the stream's
     measured half."""
     rng = np.random.default_rng(1)
@@ -185,7 +186,7 @@ def _broker(engine="device"):
     backend = lambda q: np.tile(np.asarray(q)[:, None], (1, 2)).astype(np.int32)  # noqa: E731
     spec = ServingSpec(
         cache=CacheSpec.from_strategy("STDv_LRU", 512, f_s=0.2, f_t=0.6),
-        value_dim=2, engine=engine, microbatch=BATCH,
+        value_dim=2, engine=engine, microbatch=microbatch,
         bucket=BucketSpec(min_size=8) if engine == "device" else BucketSpec(mode="none"),
     )
     return Broker.from_spec(spec, stats, [backend], value_fn=backend), keys[6000:]
@@ -208,6 +209,50 @@ def test_replay_rounds_is_the_depth_of_plan_segments():
             assert broker.stats.admitted > before  # the batch wrote
             want += int(jnp.max(plan_segments(set_idx)[3]))
         assert broker.stats.replay_rounds == want > 10
+
+
+def _depth(broker, q):
+    """The padded length and the replay depth of the batch ``q``, as
+    ``Broker.serve`` pads and places it."""
+    h_hi, h_lo = pack_hashes(splitmix64(q))
+    parts = np.asarray(broker.cache.parts_for(broker.topic_of(q)), np.int32)
+    bp = broker.bucket.padded_len(len(q))
+    h_hi, h_lo, parts, _, _ = pad_batch(h_hi, h_lo, parts, broker.cache.k, bp)
+    set_idx = broker.cache._set_index(jnp.asarray(h_lo), jnp.asarray(parts))
+    return bp, int(jnp.max(plan_segments(set_idx)[3]))
+
+
+def test_replay_lane_rounds_is_the_schedules_count():
+    broker, stream = _broker(microbatch=4096)
+    want = 0
+    with broker:
+        hot = np.full(700, stream[0])  # one set 700 deep inside a 4096 batch
+        for q in (stream[:50], stream[50:350], np.concatenate([hot, stream[350:3746]])):
+            bp, depth = _depth(broker, q)
+            before = broker.stats.admitted
+            broker.serve(q)
+            assert broker.stats.admitted > before  # the batch wrote
+            want += replay_lane_rounds(bp, depth)
+        assert broker.stats.replay_lane_rounds == want
+        assert want < 4096 * 700
+
+
+def test_replay_lane_rounds_is_bp_times_rounds_up_to_128():
+    broker, stream = _broker()
+    with broker:
+        for lo in range(0, 6 * 50, 50):  # ragged: 50 requests pad to 64
+            broker.serve(stream[lo : lo + 50])
+        assert broker.stats.replay_rounds > 6
+        assert broker.stats.replay_lane_rounds == 64 * broker.stats.replay_rounds
+
+
+def test_replay_lane_rounds_narrows_one_deep_segment_at_4096():
+    broker, stream = _broker(microbatch=4096)
+    with broker:
+        broker.serve(np.full(4096, stream[0]))  # one set holds the batch
+        assert broker.stats.replay_rounds == 4096
+        assert broker.stats.replay_lane_rounds == replay_lane_rounds(4096, 4096)
+        assert broker.stats.replay_lane_rounds < 4096 * broker.stats.replay_rounds
 
 
 def test_counters_stay_out_of_warm_up():
@@ -287,16 +332,19 @@ def test_backend_scorer_has_a_name():
 # -- checkpoints from before the counters ----------------------------------
 
 
+_NEW_COUNTERS = ("readbacks", "uploads", "replay_rounds", "replay_lane_rounds")
+
+
 def test_checkpoint_without_the_new_counters_restores_them_as_zero():
     broker, stream = _broker()
     with broker, tempfile.TemporaryDirectory() as d:
         for lo in range(0, 4 * BATCH, BATCH):
             broker.serve(stream[lo : lo + BATCH])
-        assert broker.stats.readbacks and broker.stats.uploads and broker.stats.replay_rounds
+        assert all(getattr(broker.stats, k) for k in _NEW_COUNTERS)
         broker.save(d, 1)
         npz = os.path.join(d, "step_0000000001", "arrays.npz")
         arrays = dict(np.load(npz))
-        for k in ("readbacks", "uploads", "replay_rounds"):
+        for k in _NEW_COUNTERS:
             del arrays[f"stats/{k}"]
         np.savez(npz, **arrays)
         fresh, _ = _broker()
@@ -304,7 +352,7 @@ def test_checkpoint_without_the_new_counters_restores_them_as_zero():
             fresh.serve(stream[:BATCH])  # nonzero counters before the restore
             assert fresh.restore(d) == 1
             got, want = dataclasses.asdict(fresh.stats), dataclasses.asdict(broker.stats)
-            for k in ("readbacks", "uploads", "replay_rounds"):
+            for k in _NEW_COUNTERS:
                 assert got.pop(k) == 0
                 want.pop(k)
             assert got == want

@@ -152,6 +152,13 @@ class BrokerStats:
     #: a loop over all ``bp`` lanes runs (equal for ``bp <= 128``), so
     #: the ratio says how far the tiers narrowed the replay
     replay_lane_rounds: int = 0
+    #: requests of this shard's slice, summed over the cluster batches in
+    #: which it was the largest slice: counted by a sharded cluster's
+    #: front end as it routes each batch (0 at one shard).  Summed over
+    #: the shards it is the largest slice of every batch, so
+    #: ``slice_max_requests / (requests / shards)`` is how far the
+    #: largest slice exceeds an even split
+    slice_max_requests: int = 0
     #: the online popularity tracker's state: exponentially-decayed served
     #: request counts per tracked topic (sorted id order) + a trailing
     #: no-topic bucket; shares memory with ``Broker.tracker`` and is None
@@ -164,7 +171,10 @@ class BrokerStats:
 
 
 #: counters newer than some checkpoints: a restore reads them as 0
-_LATER_COUNTERS = ("readbacks", "uploads", "replay_rounds", "replay_lane_rounds")
+_LATER_COUNTERS = (
+    "readbacks", "uploads", "replay_rounds", "replay_lane_rounds",
+    "slice_max_requests",
+)
 
 Backend = Callable[[np.ndarray], np.ndarray]  # query ids -> values (B, V)
 

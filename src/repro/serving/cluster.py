@@ -55,7 +55,14 @@ Tracing: every batch gets a sequence number, carried as the metadata
 shard split) and one ``broker.serve`` per shard slice (the broker's own
 phase spans below it; see :mod:`repro.serving.broker`).  Under
 :meth:`serve_async` the route is traced at enqueue and each drain step
-is a ``cluster.serve`` span carrying its first batch's ``seq``.
+is a ``cluster.serve`` span carrying its first batch's ``seq``.  With
+more than one shard the front end also opens ``cluster.gather`` (``seq``,
+``last_shard``) in its own thread, from the first shard slice's
+completion to the last one's -- the wait on the straggler -- and, as
+each batch is routed, adds its largest slice to ``slice_max_requests``
+on the stats of the shard that holds it (at routing, so fusing queued
+batches into one call per shard does not change the count).  One shard
+opens and counts neither.
 """
 from __future__ import annotations
 
@@ -129,6 +136,29 @@ class _ShardCall:
         self.seq: Optional[int] = None
         self.err: Optional[Exception] = None
         self.not_before = 0.0  # wall-clock deadline for the next attempt
+
+
+class _Gather:
+    """The ``cluster.gather`` span of one scatter-gather: opened in the
+    front end's thread when the first shard slice completes, closed once
+    the last one has been scattered, naming the shard that came last."""
+
+    def __init__(self, seq: Optional[int]):
+        self._seq = seq
+        self._span: Optional[TraceAnnotation] = None
+        self._last: Optional[int] = None
+
+    def completed(self, shard: int) -> None:
+        if self._span is None:
+            self._span = TraceAnnotation("cluster.gather", seq=self._seq)
+            self._span.__enter__()
+        self._last = shard
+
+    def close(self) -> None:
+        if self._span is not None:
+            self._span.set_metadata(last_shard=self._last)
+            self._span.__exit__(None, None, None)
+            self._span = None
 
 
 class ClusterFuture:
@@ -311,7 +341,9 @@ class Cluster:
 
         Returns ``(topics, h64, shard)``.  ``h64``/``shard`` are None at
         shards=1 (nothing to route; the broker hashes itself, so the
-        single-shard path stays byte-for-byte the bare broker's)."""
+        single-shard path stays byte-for-byte the bare broker's).  With
+        more than one shard it counts the batch's largest slice on the
+        shard that holds it."""
         topics = (
             np.asarray(self.topic_of(query_ids))
             if self.spec.routing == "topic"
@@ -320,7 +352,12 @@ class Cluster:
         if self.spec.shards == 1:
             return topics, None, None
         h64 = splitmix64(query_ids)
-        return topics, h64, self.spec.shard_of_hashes(h64, topics=topics)
+        shard = self.spec.shard_of_hashes(h64, topics=topics)
+        if len(shard):
+            slices = np.bincount(shard)
+            big = int(slices.argmax())
+            self.brokers[big].stats.slice_max_requests += int(slices[big])
+        return topics, h64, shard
 
     def serve(self, query_ids: np.ndarray):
         """Serve one batch -> (values (B, V), hit mask), arrival order.
@@ -610,9 +647,19 @@ class Cluster:
         behind a slower healthy shard."""
         if not calls:
             return
-        if self._pool is not None and len(calls) > 1:
-            self._execute_threaded(calls)
-            return
+        gather = _Gather(min(c.batch for c in calls)) if self.spec.shards > 1 else None
+        try:
+            if self._pool is not None and len(calls) > 1:
+                self._execute_threaded(calls, gather)
+            else:
+                self._execute_serial(calls, gather)
+        finally:
+            if gather is not None:
+                gather.close()
+
+    def _execute_serial(
+        self, calls: List[_ShardCall], gather: Optional[_Gather]
+    ) -> None:
         pending = list(calls)
         while pending:
             now_w = time.monotonic()
@@ -628,9 +675,13 @@ class Cluster:
             if out is _RETRY:
                 pending.append(ready)
             else:
+                if gather is not None:
+                    gather.completed(ready.i)
                 ready.on_done(*out)
 
-    def _execute_threaded(self, calls: List[_ShardCall]) -> None:
+    def _execute_threaded(
+        self, calls: List[_ShardCall], gather: Optional[_Gather]
+    ) -> None:
         pending = list(calls)  # backed off / not yet submitted
         futs = {}
         while pending or futs:
@@ -655,6 +706,8 @@ class Cluster:
                 if out is _RETRY:
                     pending.append(c)
                 else:
+                    if gather is not None:
+                        gather.completed(c.i)
                     c.on_done(*out)
 
     def _attempt(self, c: _ShardCall):

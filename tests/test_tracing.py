@@ -109,9 +109,11 @@ def _inside(inner, outer):
 
 
 def _check_tree(spans, shards):
-    """Every batch: one cluster.serve holding its cluster.route and one
+    """Every batch: one cluster.serve holding its cluster.route, one
     broker.serve per non-empty shard slice, each holding the phases in
-    order; every span of the batch carries its seq."""
+    order, and with more than one shard one cluster.gather in the
+    front end's thread, from a slice's completion to the last slice's;
+    every span of the batch carries its seq."""
     roots = [s for s in spans if s[0] == "cluster.serve"]
     assert [r[4]["seq"] for r in roots] == list(range(1, len(roots) + 1))
     for root in roots:
@@ -135,8 +137,17 @@ def _check_tree(spans, shards):
                 phases.remove("broker.flush")
                 flushes += 1
             assert phases == PHASES
+        gathers = [s for s in mine if s[0] == "cluster.gather"]
+        assert len(gathers) == (shards > 1)
+        for g in gathers:
+            assert g[3] == root[3]  # the front end's thread
+            assert g[4]["last_shard"] in {s[4]["shard"] for s in serves}
+            # opens once a slice has come back, closes after the last one
+            assert min(s[2] for s in serves) <= g[1]
+            assert max(s[2] for s in serves) <= g[2]
         # no other program span belongs to the batch
-        assert len(mine) == 2 + len(serves) * (1 + len(PHASES)) + flushes
+        assert len(mine) == (
+            2 + len(gathers) + len(serves) * (1 + len(PHASES)) + flushes)
     return roots
 
 
@@ -164,8 +175,11 @@ def test_four_shards_report_the_same_span_names(tmp_path):
     assert max(len([s for s in spans4 if s[0] == "broker.serve" and s[4]["seq"] == r[4]["seq"]])
                for r in roots) > 1
     spans1, _ = _traced_serve(1, str(tmp_path / "t1"))
-    # full batches on one shard never outgrow their bucket, so never flush
-    assert {s[0] for s in spans4} - {"broker.flush"} == {s[0] for s in spans1}
+    # full batches on one shard never outgrow their bucket, so never
+    # flush; one shard has no gather
+    assert "cluster.gather" in {s[0] for s in spans4}
+    assert ({s[0] for s in spans4} - {"broker.flush", "cluster.gather"}
+            == {s[0] for s in spans1})
 
 
 def test_counters_per_one_call_batch(tmp_path):

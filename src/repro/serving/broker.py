@@ -41,14 +41,16 @@ compile-count regression tests pin, and compiles as a program named
 Tracing: ``serve`` opens ``jax.profiler.TraceAnnotation`` spans for its
 phases -- ``broker.serve`` (metadata: shard, batch seq, b, padded bp)
 around ``broker.prepare`` (hash, route, pad, freshness arrays,
-admission), ``broker.dispatch`` (the pending fill plan, the uploads and
-the jitted call, which returns once enqueued), ``broker.readback`` (the
-wait for the step and the copy of its outputs), ``broker.miss``
-(coalescing and the backend) and ``broker.fill_plan`` (the deferred
-fill's plan), plus ``broker.flush`` for a standalone fill.  They land in
-the same trace, on the same clock, as the device's operations, and cost
-one check each when no profiler session is active.  ``BrokerStats``
-counts the transfers behind them (``readbacks``, ``uploads``) and the
+admission), ``broker.dispatch`` (the pending fill plan, the uploads, the
+jitted call, which returns once enqueued, and the request that every
+host-bound output be copied to the host as soon as the step ends),
+``broker.readback`` (the wait for the step and those copies),
+``broker.miss`` (coalescing and the backend) and ``broker.fill_plan``
+(the deferred fill's plan, from arrays already copied), plus
+``broker.flush`` for a standalone fill.  They land in the same trace, on
+the same clock, as the device's operations, and cost one check each when
+no profiler session is active.  ``BrokerStats`` counts the transfers
+behind them (``readbacks``, ``uploads``, ``early_copies``) and the
 conflict replay's depth (``replay_rounds``) and width
 (``replay_lane_rounds``).
 
@@ -134,8 +136,14 @@ class BrokerStats:
     #: topic/flush event for epoch-bump invalidations)
     invalidations: int = 0
     #: device->host array fetches ``serve`` issued (seven per batch on the
-    #: one-call path: hit, layer, stale, value, then wrote, set_idx, way)
+    #: one-call path: hit, layer, stale, value, then wrote, set_idx, way;
+    #: five when the batch wrote nothing)
     readbacks: int = 0
+    #: device->host copies started at dispatch, before any fetch waits:
+    #: every host-bound output of the step (seven per batch on the
+    #: one-call path, four on the unfused probe; none on the host
+    #: engine's numpy outputs); warm-up is not counted
+    early_copies: int = 0
     #: host->device arrays the serving path uploaded (ten per batch on the
     #: one-call path: four fill-plan arrays and six request arrays);
     #: warm-up uploads are not counted
@@ -173,7 +181,7 @@ class BrokerStats:
 #: counters newer than some checkpoints: a restore reads them as 0
 _LATER_COUNTERS = (
     "readbacks", "uploads", "replay_rounds", "replay_lane_rounds",
-    "slice_max_requests",
+    "slice_max_requests", "early_copies",
 )
 
 Backend = Callable[[np.ndarray], np.ndarray]  # query ids -> values (B, V)
@@ -365,6 +373,18 @@ class Broker:
         if isinstance(x, jax.Array):
             self.stats.readbacks += 1
         return np.array(x) if copy else np.asarray(x)
+
+    def _start_copies(self, *outputs) -> None:
+        """Ask for each device array among a step's ``outputs`` to be
+        copied to the host as soon as the step ends, counted in
+        ``stats.early_copies``.  The transfers queue behind the step back
+        to back; a later :meth:`_fetch` waits for its copy instead of
+        issuing one, so the fetches pay one wait and not one round trip
+        each.  Numpy outputs (the host engine's) pass untouched."""
+        for x in outputs:
+            if isinstance(x, jax.Array):
+                x.copy_to_host_async()
+                self.stats.early_copies += 1
 
     def _put(self, *arrays) -> list:
         """Upload host arrays for a serving call, counted in
@@ -702,6 +722,7 @@ class Broker:
         hit, layer, value, stale = self._probe(
             self.state, *self._put(h_hi, h_lo, parts, min_ep)
         )
+        self._start_copies(hit, layer, value, stale)
         hit = self._fetch(hit)[:b]
         layer = self._fetch(layer)[:b]
         stale = self._fetch(stale)[:b]
@@ -828,7 +849,14 @@ class Broker:
         bucket-padded tail (``len(h_hi) >= len(query_ids)``) of reserved
         pad keys -- inert in the engines, sliced off the outputs here.
         ``min_ep``/``eps`` are the batch's freshness floors and write
-        epochs (zeros without a spec); expiry rides the same call."""
+        epochs (zeros without a spec); expiry rides the same call.
+
+        On the device engine all seven host-bound outputs (``hit``,
+        ``layer``, ``value``, ``stale``, ``set_idx``, ``wrote``, ``way``)
+        start their copies to the host right after the call is enqueued
+        (:meth:`_start_copies`), so ``broker.readback`` waits once for
+        the step and the copies, and :meth:`_plan_fill` finds its arrays
+        on the host; the new state stays on the device."""
         b = len(query_ids)
         bp = len(h_hi)
         if self.engine == "host":
@@ -868,6 +896,7 @@ class Broker:
                         self.state,
                         *self._put(*plan, h_hi, h_lo, parts, admit, eps, min_ep),
                     )
+                    self._start_copies(hit, layer, value, stale, set_idx, wrote, way)
                 # the plan is consumed only once the call was issued
                 # against it: a raise above leaves it pending, so a retry
                 # or flush() still lands the values instead of losing them
@@ -931,7 +960,10 @@ class Broker:
         inserts and stale revalidations write).  Where the set indices
         come back to the host anyway, the replay's depth is counted from
         them: the largest number of the padded batch's requests in one
-        set, and the lane-rounds the tiered replay runs at that depth."""
+        set, and the lane-rounds the tiered replay runs at that depth.
+        On the device engine the three fetches here (``wrote``, then
+        ``set_idx`` and ``way`` where the batch wrote) read copies
+        started at dispatch, before the backend ran."""
         wrote_np = self._fetch(wrote)
         if not wrote_np.any():
             return

@@ -12,12 +12,14 @@ Pins the three contracts PR 10 introduced:
   a bare broker and on a shards=1 cluster, and warmup is idempotent.
 * **Conformance** -- one-call serving is request-for-request identical
   to the legacy 2/3-dispatch fused path and to the host engine, with
-  freshness on and off; and the fused kernel (`serve_fused_op`) is
+  freshness on and off, a one- or four-shard device cluster matches the
+  host engine's, its stats included; and the fused kernel (`serve_fused_op`) is
   bit-exact against the sequential numpy oracle (`serve_fused_ref`)
   under ragged final tiles, all-pad batches, all-static-hit batches and
   duplicate keys (hypothesis sweeps the same space harder when
   installed).
 """
+import dataclasses
 import json
 
 import jax
@@ -125,6 +127,44 @@ def test_one_call_matches_legacy_and_host_request_for_request(fresh):
     assert np.array_equal(np.asarray(one.state["ks"]), np.asarray(legacy.state["ks"]))
     for b in (ref, one, legacy):
         b.close()
+
+
+#: what the engines count differently by construction: the device
+#: engine's transfers
+_TRANSFERS = ("readbacks", "uploads", "early_copies")
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_one_call_cluster_matches_host_engine_and_its_stats(shards):
+    rng = np.random.default_rng(8)
+    nq, n = 500, 4000
+    keys = rng.integers(0, nq, size=n).astype(np.int64)
+    topic = rng.integers(-1, 4, size=nq).astype(np.int64)
+    stats = VecStats.from_log(VecLog(keys=keys, n_train=n // 2, key_topic=topic))
+    backend = _backend(2)
+    spec = ServingSpec(
+        cache=CacheSpec.from_strategy("STDv_LRU", 256, f_s=0.2, f_t=0.6),
+        value_dim=2, shards=shards, bucket=BucketSpec(min_size=8),
+    )
+    clusters = [
+        Cluster.from_spec(dataclasses.replace(spec, engine=e), stats, [backend],
+                          value_fn=backend)
+        for e in ("host", "device")
+    ]
+    with clusters[0] as host, clusters[1] as device:
+        for sz in RAGGED * 2:
+            q = rng.integers(0, nq, size=sz)
+            v0, h0 = host.serve(q)
+            v1, h1 = device.serve(q)
+            assert np.array_equal(v1, v0) and np.array_equal(h1, h0), sz
+        for hb, db in zip(host.brokers, device.brokers):
+            assert db.stats.batches > 0
+            assert db.stats.early_copies == 7 * db.stats.batches
+            got, want = dataclasses.asdict(db.stats), dataclasses.asdict(hb.stats)
+            for k in _TRANSFERS:
+                assert want.pop(k) == 0, k
+                got.pop(k)
+            assert got == want
 
 
 # -- dispatch-count regression -----------------------------------------------

@@ -6,7 +6,9 @@ A small device-engine cluster serves a few batches under
 read back from the trace's ``/host:CPU`` plane and must form the
 documented tree, one ``broker.serve`` per shard slice, every span of a
 batch carrying the batch's ``seq``.  ``BrokerStats`` must count seven
-readbacks and ten uploads per one-call batch, the conflict replay's
+readbacks, ten uploads and seven copies started at dispatch per one-call
+batch (every copy asked for before the batch's first fetch), the
+conflict replay's
 depth exactly as ``ops.plan_segments`` gives it, and the lane-rounds
 ``ops.replay_schedule`` runs at that depth.  The four-shard case
 runs in a child process on four virtual CPU devices.
@@ -89,7 +91,8 @@ def traced_serve(shards, trace_dir, batches=4, batch=64):
         if e.name.startswith(("cluster.", "broker."))
     ]
     counts = {f: getattr(s1, f) - getattr(s0, f)
-              for f in ("readbacks", "uploads", "replay_rounds", "batches")}
+              for f in ("readbacks", "uploads", "early_copies", "replay_rounds",
+                        "batches")}
     return sorted(spans, key=lambda s: (s[1], -s[2])), counts
 
 
@@ -157,18 +160,26 @@ def test_spans_form_the_tree_on_one_shard(tmp_path):
     assert len(roots) == counts["batches"] == 4
 
 
-def test_four_shards_report_the_same_span_names(tmp_path):
+@pytest.fixture(scope="module")
+def four_shards(tmp_path_factory):
+    """Spans and counter deltas of the traced serve on four shards, run
+    in a child process on four virtual CPU devices."""
+    tmp = tmp_path_factory.mktemp("four_shards")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(ROOT, "src"), env.get("PYTHONPATH")) if p)
-    script = tmp_path / "traced_serve.py"
+    script = tmp / "traced_serve.py"
     script.write_text(_TRACED_SERVE)
-    out = subprocess.run([sys.executable, str(script), "4", str(tmp_path / "t4")],
+    out = subprocess.run([sys.executable, str(script), "4", str(tmp / "t4")],
                          env=env, capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-4000:]
-    spans4, _ = json.loads(out.stdout.strip().splitlines()[-1])
-    spans4 = [tuple(s) for s in spans4]
+    spans4, counts = json.loads(out.stdout.strip().splitlines()[-1])
+    return [tuple(s) for s in spans4], counts
+
+
+def test_four_shards_report_the_same_span_names(tmp_path, four_shards):
+    spans4, _ = four_shards
     roots = _check_tree(spans4, 4)
     assert len(roots) == 4
     # the slices of a batch run on the shards' own threads
@@ -187,12 +198,68 @@ def test_counters_per_one_call_batch(tmp_path):
     assert counts["batches"] == 4
     assert counts["readbacks"] == 7 * 4
     assert counts["uploads"] == 10 * 4
+    assert counts["early_copies"] == 7 * 4
     assert counts["replay_rounds"] >= 4
 
 
-def _broker(engine="device", microbatch=BATCH):
+def test_counters_per_one_call_batch_on_four_shards(four_shards):
+    spans, counts = four_shards
+    slices = counts["batches"]  # summed over the shards: one per served slice
+    assert slices > 4
+    flushes = sum(s[0] == "broker.flush" for s in spans)  # four uploads each
+    assert counts["readbacks"] == 7 * slices
+    assert counts["early_copies"] == 7 * slices
+    assert counts["uploads"] == 10 * slices + 4 * flushes
+
+
+def _record_transfers(monkeypatch):
+    """Record, in order, every copy to the host asked of a device array
+    and every ``Broker._fetch`` of one, as ``(kind, id(array))``."""
+    events = []
+    array_type = type(jnp.zeros(1))
+    start, fetch = array_type.copy_to_host_async, Broker._fetch
+
+    def recorded_copy(x):
+        events.append(("copy", id(x)))
+        return start(x)
+
+    def recorded_fetch(self, x, copy=False):
+        if isinstance(x, jax.Array):
+            events.append(("fetch", id(x)))
+        return fetch(self, x, copy)
+
+    monkeypatch.setattr(array_type, "copy_to_host_async", recorded_copy)
+    monkeypatch.setattr(Broker, "_fetch", recorded_fetch)
+    return events
+
+
+@pytest.mark.parametrize("path, copies, fetches", [
+    ({}, 7, (5, 7)),  # one call; five fetches where the batch wrote nothing
+    ({"fused_one_call": False}, 7, (5, 7)),  # the legacy fused pair
+    ({"fused": False}, 4, (4,)),  # unfused: the probe's outputs
+])
+def test_copies_start_at_dispatch_before_the_first_fetch(monkeypatch, path, copies,
+                                                         fetches):
+    broker, stream = _broker(**path)
+    with broker:
+        events = _record_transfers(monkeypatch)
+        for lo in range(0, 6 * 50, 50):  # ragged: 50 requests pad to 64
+            before = broker.stats.early_copies
+            broker.serve(stream[lo : lo + 50])
+            kinds = [k for k, _ in events]
+            assert kinds[:copies] == ["copy"] * copies
+            assert kinds[copies:] == ["fetch"] * (len(kinds) - copies)
+            assert len(kinds) - copies in fetches
+            started = {i for k, i in events if k == "copy"}
+            assert len(started) == copies  # distinct arrays
+            assert {i for k, i in events if k == "fetch"} <= started
+            assert broker.stats.early_copies - before == copies
+            events.clear()
+
+
+def _broker(engine="device", microbatch=BATCH, **kw):
     """A broker on one shard's worth of a small stream, and the stream's
-    measured half."""
+    measured half; ``kw`` are further ``ServingSpec`` fields."""
     rng = np.random.default_rng(1)
     keys = rng.integers(0, 3000, size=12000)
     topic = rng.integers(-1, 4, size=3000)
@@ -202,6 +269,7 @@ def _broker(engine="device", microbatch=BATCH):
         cache=CacheSpec.from_strategy("STDv_LRU", 512, f_s=0.2, f_t=0.6),
         value_dim=2, engine=engine, microbatch=microbatch,
         bucket=BucketSpec(min_size=8) if engine == "device" else BucketSpec(mode="none"),
+        **kw,
     )
     return Broker.from_spec(spec, stats, [backend], value_fn=backend), keys[6000:]
 
@@ -274,6 +342,7 @@ def test_counters_stay_out_of_warm_up():
     with broker:
         broker.warmup()
         assert broker.stats.uploads == broker.stats.readbacks == 0
+        assert broker.stats.early_copies == 0
 
 
 def test_host_engine_fetches_nothing_from_a_device():
@@ -282,6 +351,7 @@ def test_host_engine_fetches_nothing_from_a_device():
         for lo in range(0, 3 * BATCH, BATCH):
             broker.serve(stream[lo : lo + BATCH])
         assert broker.stats.readbacks == broker.stats.uploads == 0
+        assert broker.stats.early_copies == 0
         assert broker.stats.replay_rounds >= 3
 
 
@@ -346,7 +416,8 @@ def test_backend_scorer_has_a_name():
 # -- checkpoints from before the counters ----------------------------------
 
 
-_NEW_COUNTERS = ("readbacks", "uploads", "replay_rounds", "replay_lane_rounds")
+_NEW_COUNTERS = ("readbacks", "uploads", "replay_rounds", "replay_lane_rounds",
+                 "early_copies")
 
 
 def test_checkpoint_without_the_new_counters_restores_them_as_zero():
